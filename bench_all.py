@@ -422,7 +422,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    if bench_util.is_child():
-        main()
-    else:
-        bench_util.run_with_retries("bench_all", "suite")
+    bench_util.run(main, "bench_all", "suite")

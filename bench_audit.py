@@ -152,7 +152,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    if bench_util.is_child():
-        main()
-    else:
-        bench_util.run_with_retries("audit_overhead_frac", "fraction")
+    bench_util.run(main, "audit_overhead_frac", "fraction")

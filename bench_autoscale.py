@@ -157,7 +157,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    if bench_util.is_child():
-        main()
-    else:
-        bench_util.run_with_retries("autoscale_decision_s", "seconds")
+    bench_util.run(main, "autoscale_decision_s", "seconds")

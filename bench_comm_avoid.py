@@ -241,7 +241,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    if bench_util.is_child():
-        main()
-    else:
-        bench_util.run_with_retries("comm_avoid_speedup", "t1/t2")
+    bench_util.run(main, "comm_avoid_speedup", "t1/t2")

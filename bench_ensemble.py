@@ -163,9 +163,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    if bench_util.is_child():
-        main()
-    else:
-        bench_util.run_with_retries(
-            "ensemble_per_member_speedup_E8",
-            "x (solo_step_s / per_member_step_s)")
+    bench_util.run(main, "ensemble_per_member_speedup_E8", "x (solo_step_s / per_member_step_s)")

@@ -389,9 +389,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    if bench_util.is_child():
-        main()
-    else:
-        bench_util.run_with_retries(
-            "update_halo_effective_GBps_per_chip", "GB/s/chip"
-        )
+    bench_util.run(main, "update_halo_effective_GBps_per_chip", "GB/s/chip")

@@ -210,7 +210,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    if bench_util.is_child():
-        main()
-    else:
-        bench_util.run_with_retries("io_snapshot_overhead_frac", "fraction")
+    bench_util.run(main, "io_snapshot_overhead_frac", "fraction")

@@ -180,7 +180,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    if bench_util.is_child():
-        main()
-    else:
-        bench_util.run_with_retries("membw_suite", "rows")
+    bench_util.run(main, "membw_suite", "rows")

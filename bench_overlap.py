@@ -128,8 +128,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    if bench_util.is_child():
-        main()
-    else:
-        bench_util.run_with_retries("halo_overlap_hidden_frac",
-                                    "hidden_comm/comm")
+    bench_util.run(main, "halo_overlap_hidden_frac", "hidden_comm/comm")

@@ -7,7 +7,9 @@ equality with the XLA (or numpy) reference, emitting one JSON row per kernel:
 
     {"metric": "pallas_check_<kernel>", "value": 1.0|0.0, "unit": "pass", ...}
 
-plus a summary row. Run on TPU: `python bench_pallas_check.py`.
+plus a summary row, and exits nonzero if any check failed. Run on TPU:
+`python bench_pallas_check.py`; `bench.py` runs the same `checks` in its
+own process.
 `--cpu` smoke-tests the harness itself in interpret mode (the CPU backend
 has no non-interpret pallas); only the TPU run proves Mosaic lowering.
 """
@@ -20,7 +22,10 @@ import traceback
 import bench_util
 
 
-def _checks(interpret: bool):
+def checks(interpret: bool) -> list[dict]:
+    """Run every kernel check on ONE device of the default backend and
+    return one row per check (``value`` 1.0 pass / 0.0 fail, with a
+    note). A check that raises is a failed row, not an abort."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -37,24 +42,17 @@ def _checks(interpret: bool):
     nx, ny, nz = shape
     A = jnp.asarray(rng.standard_normal(shape).astype(np.float32))
 
+    one = jax.devices()[:1]
+    rows = []
+
     def run(name, fn):
         try:
             ok, note = fn()
-            yield_row(name, bool(ok), note)
-            return bool(ok)
         except Exception:
-            yield_row(name, False, traceback.format_exc()[-600:])
-            return False
-
-    def yield_row(name, ok, note):
-        bench_util.emit({
-            "metric": f"pallas_check_{name}",
-            "value": 1.0 if ok else 0.0,
-            "unit": "pass",
-            **({"note": note} if note else {}),
-        })
-
-    results = []
+            ok, note = False, traceback.format_exc()[-600:]
+        rows.append({"metric": f"pallas_check_{name}",
+                     "value": 1.0 if ok else 0.0, "unit": "pass",
+                     **({"note": note} if note else {})})
 
     # --- in-place halo writes, dims 0 and 1 -------------------------------
     def check_write_dim0():
@@ -110,14 +108,14 @@ def _checks(interpret: bool):
         exp[:, ny - 1] = np.asarray(rys)[:, 1]
         return np.array_equal(np.asarray(out), exp), None
 
-    results.append(run("halo_write_dim0", check_write_dim0))
-    results.append(run("halo_write_dim1", check_write_dim1))
-    results.append(run("self_exchange", check_self_exchange))
-    results.append(run("combined_write", check_combined_write))
+    run("halo_write_dim0", check_write_dim0)
+    run("halo_write_dim1", check_write_dim1)
+    run("self_exchange", check_self_exchange)
+    run("combined_write", check_combined_write)
 
     # --- model kernels on a real grid (self-neighbor periodic) ------------
     igg.init_global_grid(64, 64, 256, periodx=1, periody=1, periodz=1,
-                         quiet=True)
+                         devices=one, quiet=True)
     T, Cp, p = init_diffusion3d(dtype=np.float32)
 
     def check_step_plain():
@@ -149,7 +147,7 @@ def _checks(interpret: bool):
                 Tb, Cpb, gg, modes, lam=p.lam, dt=p.dt, dx=p.dx, dy=p.dy,
                 dz=p.dz, interpret=interpret)
 
-        from implicitglobalgrid_tpu.utils.compat import shard_map
+        from jax import shard_map
 
         fused = jax.jit(shard_map(local, mesh=gg.mesh,
                                   in_specs=(spec, spec), out_specs=spec,
@@ -160,15 +158,15 @@ def _checks(interpret: bool):
         ok = np.allclose(a, b, rtol=2e-6, atol=2e-5)
         return ok, f"max_abs_diff={float(np.max(np.abs(a - b))):.3e}"
 
-    results.append(run("fused_step_self", check_step_plain))
-    results.append(run("fused_step_exchange", check_step_exchange_fused))
+    run("fused_step_self", check_step_plain)
+    run("fused_step_exchange", check_step_exchange_fused)
     igg.finalize_global_grid()
 
     # --- window-handoff variant: >= 3 windows (128/P=32 -> 4), exercising
     # the VMEM overlap handoff of `_window_pipeline_handoff` on hardware
     def check_step_handoff():
         igg.init_global_grid(128, 64, 256, periodx=1, periody=1,
-                             periodz=1, quiet=True)
+                             periodz=1, devices=one, quiet=True)
         try:
             sds = jax.ShapeDtypeStruct((128, 64, 256), np.float32)
             if not ps.mp_handoff(sds, interpret=interpret):
@@ -184,7 +182,7 @@ def _checks(interpret: bool):
         finally:
             igg.finalize_global_grid()
 
-    results.append(run("fused_step_self_handoff", check_step_handoff))
+    run("fused_step_self_handoff", check_step_handoff)
 
     # --- fused acoustic and Stokes passes (staggered multi-field tiers) ---
     from implicitglobalgrid_tpu.models import (
@@ -195,7 +193,7 @@ def _checks(interpret: bool):
 
     def check_acoustic_fused():
         igg.init_global_grid(32, 64, 256, periodx=1, periody=1, periodz=1,
-                             quiet=True)
+                             devices=one, quiet=True)
         try:
             state, pa = init_acoustic3d(dtype=np.float32)
             a = run_acoustic(state, pa, 2, nt_chunk=2, impl="xla")
@@ -208,7 +206,7 @@ def _checks(interpret: bool):
             igg.finalize_global_grid()
 
     def check_stokes_fused():
-        igg.init_global_grid(32, 64, 256, quiet=True)
+        igg.init_global_grid(32, 64, 256, devices=one, quiet=True)
         try:
             state, pstk = init_stokes3d(dtype=np.float32)
             a = run_stokes(state, pstk, 2, nt_chunk=2, impl="xla")
@@ -223,16 +221,9 @@ def _checks(interpret: bool):
         finally:
             igg.finalize_global_grid()
 
-    results.append(run("acoustic_fused", check_acoustic_fused))
-    results.append(run("stokes_fused", check_stokes_fused))
-
-    n_pass = sum(results)
-    bench_util.emit({
-        "metric": "pallas_checks_passed",
-        "value": float(n_pass),
-        "unit": f"of {len(results)}",
-        "vs_baseline": n_pass / len(results),
-    })
+    run("acoustic_fused", check_acoustic_fused)
+    run("stokes_fused", check_stokes_fused)
+    return rows
 
 
 def main() -> None:
@@ -247,11 +238,19 @@ def main() -> None:
         import jax
 
         jax.config.update("jax_platforms", "cpu")
-    _checks(interpret=cpu)  # CPU backend has no non-interpret pallas
+    rows = checks(interpret=cpu)  # CPU backend has no non-interpret pallas
+    for row in rows:
+        bench_util.emit(row)
+    n_pass = int(sum(r["value"] for r in rows))
+    bench_util.emit({
+        "metric": "pallas_checks_passed",
+        "value": float(n_pass),
+        "unit": f"of {len(rows)}",
+        "vs_baseline": n_pass / len(rows),
+    })
+    if n_pass < len(rows):
+        raise RuntimeError(f"{len(rows) - n_pass} kernel check(s) failed")
 
 
 if __name__ == "__main__":
-    if bench_util.is_child():
-        main()
-    else:
-        bench_util.run_with_retries("pallas_checks_passed", "of N")
+    bench_util.run(main, "pallas_checks_passed", "of N")

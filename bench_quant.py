@@ -188,7 +188,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    if bench_util.is_child():
-        main()
-    else:
-        bench_util.run_with_retries("quant_wire_bytes_ratio", "x")
+    bench_util.run(main, "quant_wire_bytes_ratio", "x")

@@ -192,7 +192,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    if bench_util.is_child():
-        main()
-    else:
-        bench_util.run_with_retries("bench_reshard", "suite")
+    bench_util.run(main, "bench_reshard", "suite")

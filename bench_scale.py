@@ -235,4 +235,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from implicitglobalgrid_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

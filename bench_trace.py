@@ -208,8 +208,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    if bench_util.is_child():
-        main()
-    else:
-        bench_util.run_with_retries("trace_pipeline_s_10k_events",
-                                    "seconds")
+    bench_util.run(main, "trace_pipeline_s_10k_events", "seconds")

@@ -82,7 +82,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    if bench_util.is_child():
-        main()
-    else:
-        bench_util.run_with_retries("tuned_vs_default_speedup", "t1/t2")
+    bench_util.run(main, "tuned_vs_default_speedup", "t1/t2")

@@ -105,9 +105,8 @@ def main() -> None:
     # weak-scaling efficiency curve, `reference README.md:6-8`): powers of
     # two up to n, always including n. On REAL hardware only the {1, n}
     # endpoints run (full-size nt-step measurements at every power of two
-    # would not fit the supervised attempt budget — bench_util's parent
-    # would kill the child and silently downgrade the artifact to the CPU
-    # fallback); the cheap virtual-mesh (--cpu) runs record the full curve.
+    # cost minutes each); the cheap virtual-mesh (--cpu) runs record the
+    # full curve.
     Ns = sorted({1} | ({2 ** k for k in range(1, 10) if 2 ** k <= n}
                       if cpu else set()) | {n})
 
@@ -164,10 +163,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    if bench_util.is_child():
-        main()
-    elif "--strong" in sys.argv:
-        bench_util.run_with_retries("strong_scaling_efficiency",
-                                    "rateN/(N*rate1)")
+    if "--strong" in sys.argv:
+        bench_util.run(main, "strong_scaling_efficiency", "rateN/(N*rate1)")
     else:
-        bench_util.run_with_retries("weak_scaling_efficiency", "t1/tN")
+        bench_util.run(main, "weak_scaling_efficiency", "t1/tN")

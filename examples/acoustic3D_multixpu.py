@@ -60,4 +60,7 @@ def acoustic3D():
 
 
 if __name__ == "__main__":
+    from implicitglobalgrid_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     acoustic3D()

@@ -35,6 +35,7 @@ import numpy as np
 
 import implicitglobalgrid_tpu as igg
 from implicitglobalgrid_tpu.models import diffusion_step_local, init_diffusion3d
+from implicitglobalgrid_tpu.models.common import resolve_pallas_impl
 
 
 def diffusion3D():
@@ -44,9 +45,10 @@ def diffusion3D():
     me, dims, nprocs, coords, mesh = igg.init_global_grid(nx, nx, nx)
 
     T, Cp, p = init_diffusion3d(dtype=np.float32)
+    impl = resolve_pallas_impl(None)  # the grid's tier: pallas on a TPU
 
     def step(s):
-        return {"T": diffusion_step_local(s["T"], s["Cp"], p, "xla"),
+        return {"T": diffusion_step_local(s["T"], s["Cp"], p, impl),
                 "Cp": s["Cp"]}
 
     # Supervised run with async snapshots every nvis steps (O(shard) per
@@ -109,4 +111,7 @@ def diffusion3D():
 
 
 if __name__ == "__main__":
+    from implicitglobalgrid_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     diffusion3D()

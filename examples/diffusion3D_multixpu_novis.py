@@ -65,4 +65,7 @@ def diffusion3D():
 
 
 if __name__ == "__main__":
+    from implicitglobalgrid_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     diffusion3D()

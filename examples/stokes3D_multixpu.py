@@ -74,4 +74,7 @@ def stokes3D():
 
 
 if __name__ == "__main__":
+    from implicitglobalgrid_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     stokes3D()

@@ -385,7 +385,7 @@ def make_state_runner(step_local, state_ndims, *, nt_chunk: int, key=None,
                                 tuple(state), unroll=unroll)
             return out + (run_hook(out),)
 
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     fn = jax.jit(shard_map(
         chunk, mesh=gg.mesh, in_specs=specs, out_specs=out_specs,
@@ -403,9 +403,8 @@ def make_state_runner(step_local, state_ndims, *, nt_chunk: int, key=None,
 
 def run_chunked(runner_factory, state, nt: int, nt_chunk: int):
     """Advance ``nt`` steps using ``runner_factory(chunk_size)``; compiles at
-    most two chunk sizes. Returns only after the work actually finished
-    (data-dependent `sync` — `block_until_ready` is not a reliable drain on
-    all PJRT transports, see `utils.timing.sync`)."""
+    most two chunk sizes. Returns only after the work finished on every
+    device (`utils.timing.sync`)."""
     from ..utils.timing import sync
 
     full, rem = divmod(nt, nt_chunk)

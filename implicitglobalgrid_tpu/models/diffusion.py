@@ -352,7 +352,7 @@ def make_step(p: DiffusionParams, ndim: int = 3, impl: str | None = None):
     def local(T, Cp):
         return diffusion_step_local(T, Cp, p, impl)
 
-    from ..utils.compat import shard_map
+    from jax import shard_map
     from .common import default_check_vma
 
     return jax.jit(shard_map(

@@ -408,7 +408,7 @@ def stokes_residuals(state, p: StokesParams):
             err_mom = lax.pmax(err_mom, ax)
         return err_div, err_mom
 
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     fn = jax.jit(shard_map(
         local, mesh=gg.mesh, in_specs=(spec,) * 8,

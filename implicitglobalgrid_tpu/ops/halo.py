@@ -958,7 +958,7 @@ def _build_exchange_fn(gg, sig, dims_order, coalesce, wire, stage=None):
     (`update_halo`)."""
     import jax
 
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     ndims_arr = [len(shape) for (shape, _, _) in sig]
     in_specs = tuple(field_partition_spec(nd) for nd in ndims_arr)

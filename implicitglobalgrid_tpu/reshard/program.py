@@ -126,7 +126,7 @@ def compile_reshard_program(plan: ReshardPlan, mesh):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     n_flat = plan.n_flat
     sig_progs = []

@@ -181,10 +181,10 @@ def _dict_step(names, tuple_step):
     return step
 
 
-def _setup_diffusion3d(dtype, cfg=None):
+def _setup_diffusion3d(dtype, cfg=None, impl=None):
     from ..models import diffusion_step_local, init_diffusion3d
     from ..models import diffusion as D
-    from ..models.common import resolve_comm_every
+    from ..models.common import resolve_comm_every, resolve_pallas_impl
 
     T, Cp, p = init_diffusion3d(dtype=dtype, **_tuned_knobs(cfg))
     if resolve_comm_every(p.comm_every).deep:
@@ -193,62 +193,66 @@ def _setup_diffusion3d(dtype, cfg=None):
         # JobSpec's nt then counts super-steps
         sstep, _ = D.deep_step(p)
         return _dict_step(("T", "Cp"), sstep), {"T": T, "Cp": Cp}
+    impl = resolve_pallas_impl(impl)
 
     def step(s):
-        return {"T": diffusion_step_local(s["T"], s["Cp"], p, "xla"),
+        return {"T": diffusion_step_local(s["T"], s["Cp"], p, impl),
                 "Cp": s["Cp"]}
 
     return step, {"T": T, "Cp": Cp}
 
 
-def _setup_diffusion2d(dtype, cfg=None):
+def _setup_diffusion2d(dtype, cfg=None, impl=None):
     from ..models import diffusion_step_local, init_diffusion2d
-    from ..models.common import resolve_comm_every
+    from ..models.common import resolve_comm_every, resolve_pallas_impl
 
     if cfg is not None and resolve_comm_every(cfg.comm_every).deep:
         raise InvalidArgumentError(
             "diffusion2d jobs do not support a tuned deep comm_every "
             "cadence (the 2-D builtin runs the per-step path).")
     T, Cp, p = init_diffusion2d(dtype=dtype)
+    impl = resolve_pallas_impl(impl)
 
     def step(s):
-        return {"T": diffusion_step_local(s["T"], s["Cp"], p, "xla"),
+        return {"T": diffusion_step_local(s["T"], s["Cp"], p, impl),
                 "Cp": s["Cp"]}
 
     return step, {"T": T, "Cp": Cp}
 
 
-def _setup_acoustic3d(dtype, cfg=None):
+def _setup_acoustic3d(dtype, cfg=None, impl=None):
     from ..models import acoustic_step_local, init_acoustic3d
     from ..models import acoustic as A
-    from ..models.common import resolve_comm_every
+    from ..models.common import resolve_comm_every, resolve_pallas_impl
 
     state, p = init_acoustic3d(dtype=dtype, **_tuned_knobs(cfg))
     names = ("P", "Vx", "Vy", "Vz")
     if resolve_comm_every(p.comm_every).deep:
         sstep, _ = A.deep_step(p)
         return _dict_step(names, sstep), dict(zip(names, state))
+    impl = resolve_pallas_impl(impl)
 
     def step(s):
-        out = acoustic_step_local(tuple(s[n] for n in names), p, "xla")
+        out = acoustic_step_local(tuple(s[n] for n in names), p, impl)
         return dict(zip(names, out))
 
     return step, dict(zip(names, state))
 
 
-def _setup_stokes3d(dtype, cfg=None):
+def _setup_stokes3d(dtype, cfg=None, impl=None):
     from ..models import init_stokes3d, stokes_step_local
     from ..models import stokes as S
-    from ..models.common import resolve_comm_every
+    from ..models.common import resolve_comm_every, resolve_pallas_impl
 
     state, p = init_stokes3d(dtype=dtype, **_tuned_knobs(cfg))
     names = ("P", "Vx", "Vy", "Vz", "dVx", "dVy", "dVz", "rhog")
     if resolve_comm_every(p.comm_every).deep:
         sstep, _ = S.deep_step(p)
         return _dict_step(names, sstep), dict(zip(names, state))
+    impl = resolve_pallas_impl(impl)
 
     def step(s):
-        out = stokes_step_local(tuple(s[n] for n in names), p, "xla")
+        out = stokes_step_local(tuple(s[n] for n in names), p, impl)
         return dict(zip(names, out))
 
     return step, dict(zip(names, state))
@@ -310,10 +314,15 @@ def builtin_setup(model: str, dtype: str = "float32",
             f"builtin_setup: ensemble must be >= 1; got {ensemble}.")
     import numpy as np
 
+    from ..models.common import resolve_ensemble_impl
+
     dt = np.dtype(dtype).type
 
     def setup():
-        step, state = BUILTIN_MODELS[model](dt, cfg)
+        # the tier the library selects for this grid (`make_run`'s rule);
+        # an ensemble vmaps the step, which only the XLA tier supports
+        impl = None if ensemble is None else resolve_ensemble_impl(None)
+        step, state = BUILTIN_MODELS[model](dt, cfg, impl)
         if ensemble is not None:
             from ..models.common import ensemble_state
 

@@ -142,7 +142,7 @@ def _measure_axis_link(gg, dim: int, small_bytes: int, large_bytes: int,
 
     from ..ops.fields import field_partition_spec
     from ..ops.halo import local_update_halo
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     hw = max(1, int(gg.halowidths[dim]))
 

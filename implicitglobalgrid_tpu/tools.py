@@ -87,18 +87,22 @@ def _coord_g(i0, dim: int, dcoord, size_d: int, coord):
     olp = int(gg.overlaps[dim])
     n_gl = int(gg.nxyz_g[dim])
     x0 = 0.5 * (n - size_d) * dcoord
-    x = (coord * (n - olp) + i0) * dcoord + x0
-    if bool(gg.periods[dim]):
-        x = x - dcoord
-        if np.isscalar(x) or isinstance(x, (int, float, np.generic)):
-            if x > (n_gl - 1) * dcoord:
-                x = x - n_gl * dcoord
-            if x < 0:
-                x = x + n_gl * dcoord
-        else:
-            x = jnp.where(x > (n_gl - 1) * dcoord, x - n_gl * dcoord, x)
-            x = jnp.where(x < 0, x + n_gl * dcoord, x)
-    return x
+    if not bool(gg.periods[dim]):
+        return (coord * (n - olp) + i0) * dcoord + x0
+    # The periodic wrap runs in cell units, which are exact half-integers:
+    # in coordinates, the upper wrap could land a rounding error below 0,
+    # and the lower wrap then moved the last halo cell to n_gl*d instead of
+    # onto its periodic partner at 0.
+    u = coord * (n - olp) + i0 + 0.5 * (n - size_d) - 1
+    if np.isscalar(u) or isinstance(u, (int, float, np.generic)):
+        if u > n_gl - 1:
+            u = u - n_gl
+        if u < 0:
+            u = u + n_gl
+    else:
+        u = jnp.where(u > n_gl - 1, u - n_gl, u)
+        u = jnp.where(u < 0, u + n_gl, u)
+    return u * dcoord
 
 
 def _x_g(ix, dcoord, A, dim: int, coords=None, layout=None):

@@ -202,7 +202,7 @@ def test_fixture_format_matches_live_compile():
     import implicitglobalgrid_tpu as igg
     from implicitglobalgrid_tpu.ops import halo as halo_mod
     from implicitglobalgrid_tpu.ops.fields import field_partition_spec
-    from implicitglobalgrid_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     golden = _fixture("exchange_single_axis.hlo.txt")
     igg.init_global_grid(8, 8, 8, dimx=8, dimy=1, dimz=1, periodx=1,
@@ -223,10 +223,12 @@ def test_fixture_format_matches_live_compile():
     assert len(live.permutes) == len(golden.permutes) == 2
     assert (sorted(str(live.payload_of(p)) for p in live.permutes)
             == sorted(str(golden.payload_of(p)) for p in golden.permutes))
-    assert (sorted(frozenset(p.attrs["source_target_pairs"])
-                   for p in live.permutes)
-            == sorted(frozenset(p.attrs["source_target_pairs"])
-                      for p in golden.permutes))
+    # each permute's pairs as a set, compared as a set of sets (sorting
+    # frozensets orders by subset, which is not a total order)
+    assert ({frozenset(p.attrs["source_target_pairs"])
+             for p in live.permutes}
+            == {frozenset(p.attrs["source_target_pairs"])
+                for p in golden.permutes})
     assert measure_axes(live, _ROUTES) == measure_axes(golden, _ROUTES)
     igg.finalize_global_grid()
 

@@ -1,0 +1,146 @@
+"""Compile-only checks of the main-path programs for a described TPU v5e.
+
+Nothing runs: each test builds a grid over devices of a described v5e:2x2
+topology and compiles a runner at the benchmark width through the TPU's
+own compiler, which checks what the Pallas interpreter skips (Mosaic tile
+alignment, VMEM limits, partitioning). The topology is described inside
+the fixture only: only one process may load libtpu, and under xdist every
+worker imports this file (the `on-chip-measurement` guide, section 2).
+"""
+
+import numpy as np
+import pytest
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    import jax
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        from jax.experimental import topologies
+
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it. The
+    # suite runs with x64 on (conftest); the chip path is f32, and the
+    # kernels' index arithmetic does not trace under x64.
+    was = (jax.config.jax_enable_compilation_cache,
+           jax.config.jax_enable_x64)
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_enable_x64", False)
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was[0])
+    jax.config.update("jax_enable_x64", was[1])
+
+
+@pytest.fixture
+def grid_on(topo, monkeypatch):
+    """``grid_on(n, dims, ndim=3, periodic=True)`` inits the grid on the
+    first ``prod(dims)`` described devices. `init_global_grid` executes a
+    barrier probe, which a described device cannot run: stub it."""
+    import implicitglobalgrid_tpu as igg
+    from implicitglobalgrid_tpu.utils import timing
+
+    monkeypatch.setattr(timing, "_device_barrier", lambda: None)
+
+    def init(n, dims, ndim=3, periodic=True):
+        nz = n if ndim == 3 else 1
+        per = int(periodic)
+        igg.init_global_grid(n, n, nz, dimx=dims[0], dimy=dims[1],
+                             dimz=dims[2], periodx=per, periody=per,
+                             periodz=per if ndim == 3 else 0,
+                             devices=topo.devices[:int(np.prod(dims))],
+                             select_device=False, quiet=True)
+        return igg.global_grid()
+
+    return init
+
+
+def _sds(gg, shapes, dtype):
+    """Stacked-array shapes with the grid's field sharding."""
+    import jax
+    from jax.sharding import NamedSharding
+
+    from implicitglobalgrid_tpu.ops.fields import field_partition_spec
+
+    out = []
+    for loc in shapes:
+        glob = tuple(int(n) * int(d) for n, d in zip(loc, gg.dims))
+        out.append(jax.ShapeDtypeStruct(glob, dtype, sharding=NamedSharding(
+            gg.mesh, field_partition_spec(len(loc)))))
+    return out
+
+
+def _compiled_text(runner, args):
+    return runner.lower(*args).compile().as_text()
+
+
+def _check(txt, multi):
+    assert "tpu_custom_call" in txt
+    if multi:
+        assert "collective-permute" in txt
+
+
+@pytest.mark.parametrize("n,dims,dtype", [
+    (256, (1, 1, 1), np.float32),
+    (256, (1, 1, 1), "bfloat16"),
+    (256, (2, 2, 1), np.float32),
+], ids=["1chip-f32", "1chip-bf16", "2x2x1-f32"])
+def test_diffusion3d_runner_compiles(grid_on, n, dims, dtype):
+    import jax.numpy as jnp
+
+    from implicitglobalgrid_tpu.models import DiffusionParams, make_run
+
+    gg = grid_on(n, dims)
+    d = 10.0 / (int(gg.nxyz_g[0]) - 1)
+    p = DiffusionParams(lam=1.0, dt=d * d / 8.1, dx=d, dy=d, dz=d)
+    run = make_run(p, nt_chunk=2)
+    txt = _compiled_text(run, _sds(gg, [(n, n, n)] * 2, jnp.dtype(dtype)))
+    _check(txt, multi=gg.nprocs > 1)
+
+
+def test_diffusion2d_strip_kernel_compiles(grid_on):
+    from implicitglobalgrid_tpu.models import DiffusionParams, make_run
+
+    n = 4096
+    gg = grid_on(n, (1, 1, 1), ndim=2)
+    d = 10.0 / (int(gg.nxyz_g[0]) - 1)
+    p = DiffusionParams(lam=1.0, dt=d * d / 4.1, dx=d, dy=d)
+    run = make_run(p, nt_chunk=2, ndim=2)
+    _check(_compiled_text(run, _sds(gg, [(n, n)] * 2, np.float32)),
+           multi=False)
+
+
+@pytest.mark.parametrize("dims", [(1, 1, 1), (2, 2, 1)],
+                         ids=["1chip", "2x2x1"])
+def test_acoustic3d_runner_compiles(grid_on, dims):
+    from implicitglobalgrid_tpu.models import AcousticParams, make_acoustic_run
+
+    n = 192
+    gg = grid_on(n, dims)
+    d = 10.0 / (int(gg.nxyz_g[0]) - 1)
+    p = AcousticParams(rho=1.0, K=1.0, dt=d / np.sqrt(3.1), dx=d, dy=d, dz=d)
+    run = make_acoustic_run(p, nt_chunk=2)
+    shapes = [(n, n, n), (n + 1, n, n), (n, n + 1, n), (n, n, n + 1)]
+    _check(_compiled_text(run, _sds(gg, shapes, np.float32)),
+           multi=gg.nprocs > 1)
+
+
+def test_stokes3d_runner_compiles(grid_on):
+    from implicitglobalgrid_tpu.models import StokesParams, make_stokes_run
+
+    n = 128
+    gg = grid_on(n, (1, 1, 1), periodic=False)
+    d = 10.0 / (n - 1)
+    p = StokesParams(mu=1.0, dt_v=d * d / 6.1 / 2.0, dt_p=6.1 / n,
+                     damp=1.0 - 6.0 / n, dx=d, dy=d, dz=d)
+    run = make_stokes_run(p, nt_chunk=2)
+    c, fx, fy, fz = (n, n, n), (n + 1, n, n), (n, n + 1, n), (n, n, n + 1)
+    shapes = [c, fx, fy, fz, fx, fy, fz, c]
+    _check(_compiled_text(run, _sds(gg, shapes, np.float32)), multi=False)

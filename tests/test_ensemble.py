@@ -230,7 +230,7 @@ def test_ensemble_quantized_wire_per_member_scales_roundtrip():
     )
     from implicitglobalgrid_tpu.ops import halo as halo_mod
     from implicitglobalgrid_tpu.ops.precision import resolve_wire_dtype
-    from implicitglobalgrid_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     igg.init_global_grid(4, 8, 8, dimx=8, dimy=1, dimz=1, periodx=1,
                          quiet=True)

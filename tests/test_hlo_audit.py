@@ -20,13 +20,13 @@ against checked-in golden dumps live in tests/test_analysis.py.
 
 import numpy as np
 import pytest
+from jax import shard_map
 
 import implicitglobalgrid_tpu as igg
 from implicitglobalgrid_tpu.analysis import (
     CollectiveContract, check_contract, exchange_contract, guard_contract,
     parse_program,
 )
-from implicitglobalgrid_tpu.utils.compat import shard_map
 
 pytestmark = pytest.mark.audit
 

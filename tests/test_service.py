@@ -104,6 +104,29 @@ def test_public_api_exports():
         assert sym in igg.__all__, sym
 
 
+@pytest.mark.parametrize(
+    "model", ["diffusion3d", "diffusion2d", "acoustic3d", "stokes3d"])
+def test_builtin_setup_takes_the_grid_tier(monkeypatch, model):
+    """A built-in job's step runs the tier the library selects for the
+    grid (`resolve_pallas_impl(None)` — pallas on a TPU grid), and an
+    ensemble job asks for the XLA tier its vmap needs."""
+    from implicitglobalgrid_tpu.models import common
+    from implicitglobalgrid_tpu.service.job import builtin_setup
+
+    asked = []
+    real = common.resolve_pallas_impl
+    monkeypatch.setattr(common, "resolve_pallas_impl",
+                        lambda impl, eligible=True:
+                        asked.append(impl) or real(impl, eligible))
+    nz = 1 if model == "diffusion2d" else 6
+    igg.init_global_grid(6, 6, nz, quiet=True)
+    builtin_setup(model)()
+    assert asked == [None]
+    asked.clear()
+    builtin_setup(model, ensemble=2)()
+    assert asked == ["xla"]
+
+
 def test_runspec_shim_and_validation():
     """`run_resilient` keeps its keyword surface as a thin shim over
     RunSpec; spec= and keywords are mutually exclusive; JobSpec embeds a

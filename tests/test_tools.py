@@ -95,6 +95,18 @@ def test_x_g_vec_matches_scalar():
         assert yv[i] == igg.y_g(i, 0.25, T)
 
 
+@pytest.mark.parametrize("n,d", [(16, 10.0 / 13), (256, 10.0 / 253)])
+def test_periodic_halo_coordinates_wrap_onto_partners(n, d):
+    """On a periodic axis each halo cell sits exactly on its periodic
+    partner's coordinate: the wrap runs in cell units, so ``dx =
+    lx/(nx_g-1)`` rounding cannot move the last halo cell to ``nx_g*dx``
+    (it did for these sizes, giving the initial conditions an
+    inconsistent upper halo)."""
+    igg.init_global_grid(n, 4, 4, dimx=1, periodx=1, quiet=True)
+    x = np.asarray(igg.x_g_vec(d, igg.zeros_g())).ravel()
+    assert x[0] == x[n - 2] and x[n - 1] == x[1] == 0.0
+
+
 def test_simulated_topology_mutation():
     # the reference mutates the (intentionally mutable) grid vectors to fake
     # topologies (shared.jl:57 comment; test_tools.jl:116-134) — same here.

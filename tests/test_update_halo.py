@@ -265,7 +265,7 @@ def test_local_update_halo_inside_shard_map():
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from implicitglobalgrid_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     igg.init_global_grid(5, 5, 5, dimx=2, dimy=2, dimz=2, periody=1, quiet=True)
     gg = igg.global_grid()
