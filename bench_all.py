@@ -240,16 +240,6 @@ def main() -> None:
     tracing_ok = next(r["value"] for r in tracing
                       if r["metric"] == "trace_ctx_overhead_frac") < 0.02
 
-    # --- mesh observability: trace pipeline + server-off step-loop cost ----
-    # aggregation+straggler+Perfetto-export wall time on a 10k-event
-    # two-process stream (host-only, target < 5 s) and the deterministic
-    # accounting that the step loop pays ~nothing when the metrics server
-    # is off (ISSUE 5). Config owned by `bench_trace.run_trace_overhead`.
-    import bench_trace
-
-    for row in bench_trace.run_trace_overhead(dims3, cpu):
-        results.append(bench_util.emit(row))
-
     # --- io: async snapshot overhead + vs-gather speedup -------------------
     # the snapshot pipeline's step-loop cost (submit = D2H + enqueue) as a
     # fraction of run time, target < 2%, plus the speedup over the legacy

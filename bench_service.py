@@ -18,7 +18,7 @@ each journal ``slice`` event brackets exactly one chunk-boundary
 `advance()`, whose own ``chunk`` event stamps its ``build_s + exec_s`` —
 the difference is the scheduler's added machinery, and because both
 stamps come from the SAME slice, the shared box's ±15% per-call jitter
-cancels instead of swamping the sub-1% signal (the bench_trace/
+cancels instead of swamping the sub-1% signal (the bench_telemetry/
 bench_perf lesson for bounding deterministic costs; a wall-clock A/B of
 two warm loops was tried first and its window-to-window drift exceeded
 the entire gate several-fold in both directions). What the subtraction

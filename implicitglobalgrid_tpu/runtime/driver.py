@@ -56,11 +56,30 @@ from __future__ import annotations
 
 import json
 import os
+import threading
 import time
+from typing import NamedTuple
 
 from .spec import RunSpec
 
 __all__ = ["run_resilient", "ResilientRun", "RunSpec"]
+
+# ``t``: monotonic end of the last chunk boundary on this thread, of any
+# run (a scheduler interleaves several runs on one thread): the next
+# boundary charges the time since to the ``caller`` phase
+_LAST_BOUNDARY = threading.local()
+
+
+class _Chunk(NamedTuple):
+    """One prepared chunk (`ResilientRun._prepare`)."""
+    runner: object
+    plan: object      # reducer plan, or None
+    step: int         # first step of the chunk
+    nb: int           # step after its last
+    n: int
+    sizes: list       # cells per field
+    misses0: float    # runner-cache misses before the lookup
+    build_s: float    # runner lookup (or build) host seconds
 
 
 class _CheckpointSlots:
@@ -769,6 +788,55 @@ class ResilientRun:
                                elapsed_s=elapsed_s, slack_s=slack_s)
 
     def _iterate(self):
+        """One chunk boundary in four phases, each a host profiler span
+        with stats ``chunk`` and ``step``:
+        ``igg.prepare`` (`_prepare`), ``igg.dispatch`` (the chunk
+        program's launch), ``igg.guard_fetch`` (the wait for the guard
+        vector, which drains the chunk) and ``igg.commit`` (`_commit`;
+        ``igg.perf_watch`` nests in it). The same clock reads feed
+        ``igg_boundary_seconds_total{phase}``, with ``caller`` for the
+        time since the thread's previous boundary (of any run) ended."""
+        from ..telemetry.hooks import note_boundary_phases
+        from ..utils.profiling import annotate
+
+        t_enter = time.monotonic()
+        t_left = getattr(_LAST_BOUNDARY, "t", None)
+        caller = {} if t_left is None else {"caller": t_enter - t_left}
+        _LAST_BOUNDARY.t = None
+        tags = {"chunk": self.chunk_idx, "step": self.step}
+        with annotate("igg.prepare", **tags):
+            chunk = self._prepare()
+        if chunk is None:  # an elastic restart took this boundary
+            _LAST_BOUNDARY.t = time.monotonic()
+            note_boundary_phases(**caller,
+                                 prepare=_LAST_BOUNDARY.t - t_enter)
+            return
+        with annotate("igg.dispatch", **tags):
+            t_exec0 = time.monotonic()
+            out = chunk.runner(*(self.state[k] for k in self.names))
+            t_sent = time.monotonic()
+        with annotate("igg.guard_fetch", **tags):
+            # tiny replicated fetch = the chunk drain; with reducers the
+            # vector carries [health | reducer segments] from ONE psum
+            # (ensemble: an (E, 2N+R) matrix — per-member rows, one psum)
+            vec = self._np.asarray(out[-1])
+            t_done = time.monotonic()
+        with annotate("igg.commit", **tags):
+            try:
+                self._commit(chunk, out, vec, exec_s=t_done - t_exec0,
+                             tags=tags)
+            finally:
+                _LAST_BOUNDARY.t = t_left = time.monotonic()
+                note_boundary_phases(
+                    **caller, prepare=t_exec0 - t_enter,
+                    dispatch=t_sent - t_exec0, fetch=t_done - t_sent,
+                    commit=t_left - t_done)
+
+    def _prepare(self):
+        """Heartbeat, deadline and due faults, then the chunk's bounds and
+        its (cached) runner, audited once per distinct program. Returns
+        the `_Chunk` to dispatch, or None when an elastic restart took
+        the boundary."""
         np = self._np
         record_event = self._record_event
 
@@ -777,7 +845,7 @@ class ResilientRun:
         )
         from ..utils.exceptions import ResilienceError
         from .faults import NaNPoke, ProcessLoss, poke_nan
-        from .health import make_guarded_runner, report_from_stats
+        from .health import make_guarded_runner
 
         # liveness stamp at every boundary (normal commit, retry, and
         # elastic-restart paths all come back through here): the /healthz
@@ -818,7 +886,7 @@ class ResilientRun:
             # a guard trip before the next cadence save rolls back onto
             # the live grid instead of re-crossing the dims change
             self._save(self.state, self.step)
-            return
+            return None
 
         # --- one supervised chunk ----------------------------------------
         nb = min(step + self.cur_chunk, self.nt)
@@ -907,13 +975,25 @@ class ResilientRun:
                 record_event("audit_failed", error=str(e),
                              audit_s=time.monotonic() - t_built,
                              attempt=self._audit_fail_counts[n])
-        t_exec0 = time.monotonic()
-        out = runner(*(state[k] for k in names))
-        # tiny replicated fetch = the chunk drain; with reducers the
-        # vector carries [health | reducer segments] from ONE psum
-        # (ensemble: an (E, 2N+R) matrix — per-member rows, one psum)
-        vec = np.asarray(out[-1])
-        t_done = time.monotonic()
+        return _Chunk(runner=runner, plan=plan, step=step, nb=nb, n=n,
+                      sizes=sizes, misses0=misses0,
+                      build_s=t_built - t_build0)
+
+    def _commit(self, chunk, out, vec, *, exec_s: float, tags: dict):
+        """Judge the fetched guard vector and record the chunk, then
+        commit the state (cadence save, snapshot submit) or roll back."""
+        record_event = self._record_event
+
+        from ..telemetry.hooks import (
+            record_health_event, runner_cache_misses,
+        )
+        from ..utils.exceptions import ResilienceError
+        from ..utils.profiling import annotate
+        from .health import report_from_stats
+
+        names, spec, E = self.names, self.spec, self.ensemble
+        step, nb, n, plan = chunk.step, chunk.nb, chunk.n, chunk.plan
+        sizes = chunk.sizes
         nh = 2 * len(names)
         if E:
             from .health import ensemble_reports_from_stats
@@ -944,17 +1024,18 @@ class ResilientRun:
         record_event("chunk", chunk=rep.chunk, step_begin=step,
                      step_end=nb, n=n, ok=ok,
                      reasons=reasons,
-                     build_s=t_built - t_build0,
-                     exec_s=t_done - t_exec0,
+                     build_s=chunk.build_s,
+                     exec_s=exec_s,
                      **({"members_tripped": tripped} if E else {}))
         if self.watch is not None:
             # live drift detection: pure host arithmetic per boundary (a
             # cold chunk — its dispatch paid the XLA compile after a
             # runner-cache miss — updates gauges only)
-            verdict = self.watch.observe(
-                chunk=rep.chunk, step_begin=step, step_end=nb, n=n,
-                exec_s=t_done - t_exec0,
-                cold=runner_cache_misses() > misses0)
+            with annotate("igg.perf_watch", **tags):
+                verdict = self.watch.observe(
+                    chunk=rep.chunk, step_begin=step, step_end=nb, n=n,
+                    exec_s=exec_s,
+                    cold=runner_cache_misses() > chunk.misses0)
             if verdict is not None:
                 record_event("perf_regression", **verdict)
                 self._mark_tuned_stale("perf_drift")

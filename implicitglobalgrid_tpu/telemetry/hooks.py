@@ -26,7 +26,7 @@ __all__ = ["note_runner_cache", "account_halo_exchange",
            "observe_reshard", "note_deadline_slack", "note_queue_backlog",
            "note_alert", "note_autoscale_decision",
            "note_job_target_devices", "note_http_request",
-           "note_flight_file_bytes"]
+           "note_flight_file_bytes", "note_boundary_phases"]
 
 # Metric family names (the exported contract; see docs/observability.md).
 RUNNER_CACHE = "igg_runner_cache_total"
@@ -43,6 +43,8 @@ IO_QUEUE_DEPTH = "igg_io_queue_depth"
 REDUCER_VALUE = "igg_reducer_value"
 HEARTBEAT_TS = "igg_driver_heartbeat_timestamp_seconds"
 HEARTBEAT_STEP = "igg_driver_step"
+# host wall time of the supervised driver's chunk boundaries, by phase
+BOUNDARY_SECONDS = "igg_boundary_seconds_total"
 PERF_STEP_S = "igg_perf_step_seconds"
 PERF_RATIO = "igg_perf_model_ratio"
 PERF_Z = "igg_perf_zscore"
@@ -235,6 +237,23 @@ def note_heartbeat(step) -> None:
               "boundary (unix seconds).").set(time.time())
     reg.gauge(HEARTBEAT_STEP,
               "Last step the resilient driver committed.").set(step)
+
+
+def note_boundary_phases(**seconds) -> None:
+    """Add one chunk boundary's host seconds to
+    ``igg_boundary_seconds_total{phase}``: ``caller`` from the end of
+    the thread's previous boundary, of any run, to this one's start (the
+    scheduler's or harness's own time), ``prepare`` up to the dispatch,
+    ``dispatch`` the chunk program's launch call, ``fetch`` the wait for
+    the guard vector, ``commit`` the rest. Together they partition the
+    driver thread's wall time between dispatches; an untraced run reads
+    its boundary split here (or on `/metrics`)."""
+    fam = metrics_registry().counter(
+        BOUNDARY_SECONDS,
+        "Host seconds of the supervised driver's chunk boundaries, by "
+        "phase (caller, prepare, dispatch, fetch, commit).", ("phase",))
+    for phase, s in seconds.items():
+        fam.inc(s, phase=phase)
 
 
 def observe_perf(per_step_s: float, *, ratio=None, z=None,

@@ -223,14 +223,22 @@ def record_event(kind: str, **fields) -> None:
 
 @contextlib.contextmanager
 def record_span(kind: str, **fields):
-    """Span against the current recorder; when none is active the block
+    """Span against the current recorder, opened as a profiler annotation
+    of the same name (`utils.profiling.annotate`, the scalar ``fields`` as
+    its stats): the flight record and the profiler event time one block.
+    When no recorder is active the annotation alone remains and the block
     runs untimed (no clock reads)."""
+    from ..utils.profiling import annotate
+
+    stats = {k: v for k, v in fields.items()
+             if isinstance(v, (bool, int, float, str))}
     r = getattr(_tls, "recorder", None) or _current
-    if r is None:
-        yield
-        return
-    with r.span(kind, **fields):
-        yield
+    with annotate(kind, **stats):
+        if r is None:
+            yield
+            return
+        with r.span(kind, **fields):
+            yield
 
 
 def read_flight_events(path, *, run_id: str | None = None,
