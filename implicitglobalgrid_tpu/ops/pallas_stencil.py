@@ -30,7 +30,8 @@ from functools import partial
 __all__ = ["diffusion3d_step_pallas", "diffusion3d_step_halo_pallas",
            "diffusion3d_step_halo_pallas_mp", "mp_supported",
            "pallas_supported", "fusable_halo_dims",
-           "step_exchange_modes", "diffusion3d_step_exchange_pallas",
+           "step_exchange_modes", "step_exchange_folds_z",
+           "diffusion3d_step_exchange_pallas",
            "strip_rows_2d", "diffusion2d_step_exchange_pallas"]
 
 
@@ -189,11 +190,12 @@ def diffusion3d_step_pallas(T, Cp, *, lam, dt, dx, dy, dz, interpret=False):
 # `fusable_halo_dims` only covers self-neighbor (single-shard periodic) dims;
 # on a pod every axis is multi-shard and the round-1 design fell back to
 # step-kernel + separate exchange (~4 array passes/step). This path keeps the
-# whole step at ~2 passes regardless of sharding:
+# whole step at ~2 passes where z does not cross chips:
 #
 #   1. compute the POST-update send slabs from thin input slabs (XLA — a few
-#      planes/rows/lanes, negligible traffic; valid because the update is a
-#      radius-1 stencil and the send slabs sit >= 1 cell inside the block);
+#      planes or rows; valid because the update is a radius-1 stencil and
+#      the send slabs sit >= 1 cell inside the block). A z slab is a lane
+#      column: it pads to 128 lanes in memory, a large share of a pass;
 #   2. run the `exchange_recv_slabs` pipeline on them (ppermutes / local
 #      swaps, slab-level corner patching, PROC_NULL masking) — the permutes
 #      depend ONLY on the thin slabs, so XLA's scheduler overlaps them with
@@ -201,6 +203,10 @@ def diffusion3d_step_pallas(T, Cp, *, lam, dt, dx, dy, dz, interpret=False):
 #   3. ONE Pallas pass computes the update for the whole block AND writes
 #      the received slabs (z lanes -> x planes -> y rows precedence, same
 #      corner argument as `halo_write_combined_pallas`).
+#
+# A self-neighbor z (periodic, one shard) therefore skips the pipeline: its
+# halo is a pair of lane copies, applied to the kernel's computed planes and
+# to each x/y send slab (`step_exchange_folds_z`).
 # ---------------------------------------------------------------------------
 
 
@@ -234,6 +240,28 @@ def step_exchange_modes(gg, T):
     if not any(modes):
         return None
     return tuple(modes)
+
+
+def step_exchange_folds_z(gg, modes) -> bool:
+    """Whether the fused step+exchange folds the z halo in place of
+    exchanging it: z exchanges (``modes[2]``) as a self-neighbor (one
+    shard, periodic, displacement 1; overlap 2 and halowidth 1 are
+    `step_exchange_modes`'s own gate)."""
+    return (bool(modes[2]) and int(gg.dims[2]) == 1
+            and bool(gg.periods[2]) and int(gg.disp) == 1)
+
+
+def _fold_z_halo(u):
+    """The self-neighbor z halo update as two lane selects over the last
+    axis: lane 0 <- lane nz-2, then lane nz-1 <- lane 1 (the reference's
+    local path, `update_halo.jl:62-68`, at overlap 2, halowidth 1)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    nz = u.shape[-1]
+    col = lax.broadcasted_iota(jnp.int32, u.shape, u.ndim - 1)
+    u = jnp.where(col == 0, u[..., nz - 2:nz - 1], u)
+    return jnp.where(col == nz - 1, u[..., 1:2], u)
 
 
 def _xla_update_slab(T, Cp, dim, start, size, consts):
@@ -275,11 +303,12 @@ def _xla_update_slab(T, Cp, dim, start, size, consts):
     return lax.slice_in_dim(out, start - lo, start - lo + size, axis=dim)
 
 
-def _plane_step_recv_kernel(*refs, nx, modes, lam, dt, dx, dy, dz):
+def _plane_step_recv_kernel(*refs, nx, modes, self_z, lam, dt, dx, dy, dz):
     """One output plane of the fused step + exchange: compute the update,
     then deliver the received halo slabs (z lanes, then x whole planes, then
     y rows — the reference's write order restricted to this plane; received
-    planes replace the computed one entirely, carrying their own corners)."""
+    planes replace the computed one entirely, carrying their own corners).
+    With ``self_z`` the z lanes are the plane's own (`_fold_z_halo`)."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
@@ -300,6 +329,8 @@ def _plane_step_recv_kernel(*refs, nx, modes, lam, dt, dx, dy, dz):
     col = lax.broadcasted_iota(jnp.int32, (ny, nz), 1)
     interior_yz = (row > 0) & (row < ny - 1) & (col > 0) & (col < nz - 1)
     u = jnp.where(interior_yz & (i > 0) & (i < nx - 1), upd, tc)
+    if self_z:
+        u = _fold_z_halo(u)
     if modes[2]:  # halowidth 1 throughout (step_exchange_modes)
         u = jnp.where(col == 0, rz_ref[0, :, 0:1], u)
         u = jnp.where(col == nz - 1, rz_ref[0, :, 1:2], u)
@@ -311,12 +342,13 @@ def _plane_step_recv_kernel(*refs, nx, modes, lam, dt, dx, dy, dz):
     o_ref[0] = u
 
 
-def _mp_step_recv_kernel(*refs, nx, P, modes, lam, dt, dx, dy, dz,
+def _mp_step_recv_kernel(*refs, nx, P, modes, self_z, lam, dt, dx, dy, dz,
                          handoff=False):
     """Multi-plane form of `_plane_step_recv_kernel`: P output planes per
     program from a double-buffered (P+2)-plane T window (`_window_pipeline`
     — the same HBM-traffic win as `_mp_kernel`), each delivered its
-    received slabs in the z, x, y order."""
+    received slabs in the z, x, y order (z folded in place with
+    ``self_z``)."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
@@ -351,6 +383,8 @@ def _mp_step_recv_kernel(*refs, nx, P, modes, lam, dt, dx, dy, dz,
         upd = _stencil_plane(tm, tc, tp, cp_ref[j],
                              lam=lam, dt=dt, dx=dx, dy=dy, dz=dz)
         u = jnp.where(interior_yz & (g > 0) & (g < nx - 1), upd, tc)
+        if self_z:
+            u = _fold_z_halo(u)
         if modes[2]:  # halowidth 1 throughout (step_exchange_modes)
             u = jnp.where(col == 0, rz_ref[j, :, 0:1], u)
             u = jnp.where(col == nz - 1, rz_ref[j, :, 1:2], u)
@@ -388,11 +422,18 @@ def diffusion3d_step_exchange_pallas(T, Cp, gg, modes, *, lam, dt, dx, dy,
 
     from .precision import resolve_wire_dtype
 
-    recvs = exchange_recv_slabs(
-        gg, T.shape, (1, 1, 1), modes,
-        lambda dim, start, size: _xla_update_slab(T, Cp, dim, start, size,
-                                                  consts),
-        wire=resolve_wire_dtype(None))
+    # A self-neighbor z stays out of the pipeline: every x/y slab (sends
+    # and PROC_NULL current halos) takes the z lane copy first, which is
+    # what patching it with the z recvs gives, since z comes first.
+    self_z = step_exchange_folds_z(gg, modes)
+    modes = (bool(modes[0]), bool(modes[1]), bool(modes[2]) and not self_z)
+
+    def get_slab(dim, start, size):
+        slab = _xla_update_slab(T, Cp, dim, start, size, consts)
+        return _fold_z_halo(slab) if self_z else slab
+
+    recvs = exchange_recv_slabs(gg, T.shape, (1, 1, 1), modes, get_slab,
+                                wire=resolve_wire_dtype(None))
 
     P = mp_planes(T, interpret=interpret)
     mp = P is not None
@@ -439,7 +480,7 @@ def diffusion3d_step_exchange_pallas(T, Cp, gg, modes, *, lam, dt, dx, dy,
     if mp:
         kernel = partial(_mp_step_recv_kernel, nx=nx, P=P,
                          handoff=mp_handoff(T, interpret=interpret),
-                         modes=tuple(bool(m) for m in modes), **consts)
+                         modes=modes, self_z=self_z, **consts)
         return pl.pallas_call(
             kernel,
             grid=(nx // P,),
@@ -452,8 +493,8 @@ def diffusion3d_step_exchange_pallas(T, Cp, gg, modes, *, lam, dt, dx, dy,
             **_sequential_grid_params(interpret),
         )(*operands)
 
-    kernel = partial(_plane_step_recv_kernel, nx=nx,
-                     modes=tuple(bool(m) for m in modes), **consts)
+    kernel = partial(_plane_step_recv_kernel, nx=nx, modes=modes,
+                     self_z=self_z, **consts)
     return pl.pallas_call(
         kernel,
         grid=(nx,),

@@ -87,6 +87,22 @@ def _check(txt, multi):
         assert "collective-permute" in txt
 
 
+def _assert_z_folded(txt, n):
+    """On a 2x2x1 mesh z is a self-neighbor axis: the fused step+exchange
+    folds its halo into the kernel and the x/y send slabs, so the program
+    holds no lane-sparse z slab (minor dim 1-3, padded to 128 lanes), the
+    kernel takes no (n, n, 2) z operand, and T is never copied whole into
+    a z-major layout."""
+    import re
+
+    assert not re.search(rf"f32\[{n},{n},[123]\]", txt)
+    for line in txt.splitlines():
+        if "tpu_custom_call" in line:
+            assert f"f32[{n},{n},2]" not in line
+        assert not re.search(
+            rf"= f32\[{n},{n},{n}\]\{{1,0,2\b[^}}]*\}} copy\(", line)
+
+
 @pytest.mark.parametrize("n,dims,dtype", [
     (256, (1, 1, 1), np.float32),
     (256, (1, 1, 1), "bfloat16"),
@@ -103,6 +119,8 @@ def test_diffusion3d_runner_compiles(grid_on, n, dims, dtype):
     run = make_run(p, nt_chunk=2)
     txt = _compiled_text(run, _sds(gg, [(n, n, n)] * 2, jnp.dtype(dtype)))
     _check(txt, multi=gg.nprocs > 1)
+    if dims == (2, 2, 1):
+        _assert_z_folded(txt, n)
 
 
 def test_diffusion2d_strip_kernel_compiles(grid_on):

@@ -409,6 +409,28 @@ def test_fused_step_exchange_mixed_mesh_permutes():
     _assert_fused(_compiled_step_ir("pallas_interpret"), (8, 8, 16), 4)
 
 
+def test_fused_step_exchange_self_z_permutes():
+    """2x2x1 periodic (what `dims_create(4)` gives): z is a self-neighbor
+    axis folded into the kernel and the send slabs, so only x and y emit
+    collectives — 4 permutes, one pair per axis, each one (1, ny, nz) or
+    (nx, 1, nz) slab, none on z — and no op makes or takes a z slab over
+    the x-y extent."""
+    from implicitglobalgrid_tpu.analysis import axis_routes, measure_axes
+
+    igg.init_global_grid(8, 8, 16, dimx=2, dimy=2, dimz=1,
+                         periodx=1, periody=1, periodz=1, quiet=True)
+    ir = _compiled_step_ir("pallas_interpret")
+    _assert_fused(ir, (8, 8, 16), 4)
+    axes = measure_axes(ir, axis_routes())
+    assert {a: r["permutes"] for a, r in axes.items()} == {"gx": 2, "gy": 2}
+    assert {ir.payload_of(op).cells for op in ir.permutes} == {8 * 16}
+    z_slabs = [o.line for o in ir.find(dtype="f32")
+               if any(s.dims[:2] == (8, 8) and s.dims[2:] < (4,)
+                      for s in o.shapes + o.operand_shapes
+                      if len(s.dims) == 3)]
+    assert not z_slabs, z_slabs[:3]
+
+
 def test_fused_step_all_self_emits_no_collectives():
     """All-self mesh: the fused step (multi-plane kernel + in-kernel halo
     fusion) must emit NO collectives at all."""

@@ -164,20 +164,33 @@ def test_impl_resolution_from_env_flag():
     assert _resolve_impl(None) == "xla"  # device_type is cpu here
 
 
+# local nx 6 fails the multi-plane gate: the plane-per-program kernel runs
+_PLANE_KERNEL_CASE = "2x2x1 self z, plane-per-program kernel (local nx 6)"
+
+
 @pytest.mark.parametrize("dims,periods,label", [
     ((2, 2, 2), (1, 1, 1), "all multi-shard periodic"),
     ((2, 2, 2), (0, 0, 0), "all multi-shard PROC_NULL edges"),
     ((2, 1, 1), (1, 0, 0), "multi x only: partial modes (True,False,False)"),
     ((1, 2, 4), (1, 0, 1), "self x + PROC_NULL y + 4-shard z"),
+    ((2, 2, 1), (1, 1, 1), "2x2x1 periodic: self z folded"),
+    ((2, 2, 1), (0, 0, 1), "2x2x1 PROC_NULL x/y: self z folded"),
+    ((2, 1, 1), (1, 1, 1), "2x1x1 periodic: self z folded, self y swapped"),
+    ((2, 2, 1), (1, 1, 1), _PLANE_KERNEL_CASE),
 ])
 def test_step_exchange_fused_matches_xla(dims, periods, label):
     """The fused step+exchange path (thin-slab sends -> ppermute -> one
     delivery pass) must reproduce the XLA step followed by the sequential
     exchange over a 10-step whole loop — corners propagate through mixed
-    self/multi-shard dims."""
-    from implicitglobalgrid_tpu.ops.pallas_stencil import step_exchange_modes
+    self/multi-shard dims, and through a self z folded in the kernel and
+    the send slabs instead of exchanged."""
+    from implicitglobalgrid_tpu.ops.pallas_stencil import (
+        fusable_halo_dims, mp_planes, step_exchange_folds_z,
+        step_exchange_modes,
+    )
 
-    igg.init_global_grid(8, 8, 16, dimx=dims[0], dimy=dims[1], dimz=dims[2],
+    nx = 6 if label == _PLANE_KERNEL_CASE else 8
+    igg.init_global_grid(nx, 8, 16, dimx=dims[0], dimy=dims[1], dimz=dims[2],
                          periodx=periods[0], periody=periods[1],
                          periodz=periods[2], quiet=True)
     gg = igg.global_grid()
@@ -187,8 +200,14 @@ def test_step_exchange_fused_matches_xla(dims, periods, label):
     import jax
 
     loc = local_shape_of(tuple(int(s) for s in T.shape))
-    assert step_exchange_modes(
-        gg, jax.ShapeDtypeStruct(loc, T.dtype)) is not None, label
+    sds = jax.ShapeDtypeStruct(loc, T.dtype)
+    modes = step_exchange_modes(gg, sds)
+    assert modes is not None, label
+    fuse = fusable_halo_dims(gg)
+    assert fuse is None or not fuse[0], label  # not the one-pass self kernel
+    self_z = dims[2] == 1 and periods[2] == 1
+    assert step_exchange_folds_z(gg, modes) == self_z, label
+    assert (mp_planes(sds, interpret=True) is None) == (nx == 6), label
     a = np.asarray(igg.gather(make_run(p, 10, impl="xla")(T, Cp)[0]))
     b = np.asarray(igg.gather(make_run(p, 10, impl="pallas_interpret")(T, Cp)[0]))
     assert np.allclose(a, b, rtol=1e-5, atol=1e-4), label
