@@ -185,21 +185,66 @@ def _chunk_times(rec) -> dict:
             "chunks_per_5s": [bins[k] for k in sorted(bins)]}
 
 
+COMPARE_REGIONS = ("stacked", "owned")
+
+
+def comparisons(cfg: dict, phys: dict) -> dict:
+    """How `check` reads each field: ``{name: (region, of, per)}``, the
+    error over ``region`` against ``max|reference of| / per``.
+
+    From the field's entry in the configuration:
+
+    - ``"compare"``: ``"stacked"`` (the default), the whole stacked array,
+      halos included; or ``"owned"``, each global entry once from its
+      owning shard, for state whose halos the program never exchanges.
+    - ``"scale"``: by default the field's own ``max|reference|``; or
+      ``{"field": f, "over": c}``, ``max|reference f| / phys[c]``, for a
+      rate of change of ``f`` that an iteration drives towards zero, so
+      that its error is read by the change it makes to ``f`` in one step
+      of ``c``.
+
+    Either key needs a ``"why"`` beside it. An unknown region, field or
+    physics constant is a `NoResult`."""
+    out = {}
+    for k, f in cfg["fields"].items():
+        region = f.get("compare", "stacked")
+        if region not in COMPARE_REGIONS:
+            raise NoResult(f"field {k!r}: unknown compare region {region!r}; "
+                           f"have {list(COMPARE_REGIONS)}")
+        scale = f.get("scale", {"field": k})
+        if (not isinstance(scale, dict) or set(scale) - {"field", "over"}
+                or scale.get("field") not in cfg["fields"]):
+            raise NoResult(f"field {k!r}: scale {scale!r} is not "
+                           "{\"field\": <a field>, \"over\": <a constant>}")
+        of, over = scale["field"], scale.get("over")
+        per = 1.0 if over is None else phys.get(over)
+        if not isinstance(per, (int, float)) or not per > 0:
+            raise NoResult(f"field {k!r}: scale over {over!r} is no "
+                           f"positive physics constant ({sorted(phys)})")
+        if (region != "stacked" or "scale" in f) and not f.get("why"):
+            raise NoResult(f"field {k!r}: a \"compare\" or \"scale\" says "
+                           "why, in a \"why\" beside it")
+        out[k] = (region, of, per)
+    return out
+
+
 def check(cell: Cell, layout: Layout, phys: dict, kept, device,
           control: bool = False) -> dict:
     """Replay each kept chunk with the plain reference and compare, on
     ``device``.
 
     Reading: the largest, over the kept chunks and the fields, of
-    ``max|program - reference| / max|reference|`` over the whole stacked
-    array, halos included. With ``control``, the reference computed in the
+    ``max|program - reference|`` over the field's region, halos included
+    or each global entry once from its owning shard, divided by
+    ``max|reference|`` of the field or of the one its scale names
+    (`comparisons`). With ``control``, the reference computed in the
     precision below the configuration's (bfloat16 for float32) takes the
     program's place, on the same inputs."""
     import jax
     import jax.numpy as jnp
 
     dtype = jnp.dtype(cell.config["dtype"])
-    names = list(cell.config["fields"])
+    plan = comparisons(cell.config, phys)
     out = {"max_rel_err": 0.0}
 
     def rel(a, b, scale):
@@ -215,16 +260,22 @@ def check(cell: Cell, layout: Layout, phys: dict, kept, device,
                       if control else None)
         ref, low = fns[n]
         g = {k: layout.to_global(k, jax.device_put(before[k], device), jnp)
-             for k in names}
+             for k in plan}
         r = ref(g)
         lo = low(g) if control else None
         del g
-        for k in names:
-            scale = float(jnp.max(jnp.abs(r[k]))) or 1.0
-            got = (layout.to_stacked(k, lo[k], jnp) if control
-                   else jax.device_put(after[k], device))
-            out["max_rel_err"] = max(out["max_rel_err"], rel(
-                got, layout.to_stacked(k, r[k], jnp), scale))
+        for k, (region, of, per) in plan.items():
+            scale = float(jnp.max(jnp.abs(r[of]))) / per or 1.0
+            if region == "owned":
+                got = (lo[k] if control else layout.to_global(
+                    k, jax.device_put(after[k], device), jnp))
+                want = r[k]
+            else:
+                got = (layout.to_stacked(k, lo[k], jnp) if control
+                       else jax.device_put(after[k], device))
+                want = layout.to_stacked(k, r[k], jnp)
+            out["max_rel_err"] = max(out["max_rel_err"],
+                                     rel(got, want, scale))
     return out
 
 
@@ -291,6 +342,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
         layout = Layout(n, dims, {k: f["stagger"]
                                   for k, f in cfg["fields"].items()})
         phys = cell.model.physics(cfg, layout)
+        comparisons(cfg, phys)  # a bad entry ends the run before set-up
         sharding = jax.sharding.NamedSharding(gg.mesh,
                                               field_partition_spec(3))
         made = cell.model.make_state(cfg, layout, seed, sharding,
