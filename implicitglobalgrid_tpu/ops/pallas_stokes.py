@@ -34,7 +34,23 @@ from functools import partial
 
 from .pallas_common import slab1 as _slab
 
-__all__ = ["stokes_exchange_modes", "stokes_step_exchange_pallas"]
+__all__ = ["SCOPES", "stokes_exchange_modes", "stokes_step_exchange_pallas"]
+
+# Device-side name scopes of the traced step (`jax.named_scope`): they reach
+# each op's HLO metadata, so a profiler trace's op events carry them in the
+# ``tf_op`` stat of their event metadata. "pt": the fused PT pass (the
+# Mosaic custom call); "slabs": the send-slab getters' computes; "vx_planes":
+# the post-kernel writes of Vx's and dVx's extra x planes.
+SCOPES = {"pt": "igg.stokes.pt", "slabs": "igg.stokes.slabs",
+          "vx_planes": "igg.stokes.vx_planes"}
+
+# Scoped VMEM of the fused pass where it takes received y/z slabs: beside
+# the 11 state planes, 7 output planes and the full-plane intermediates
+# they bring a 256^3 local block to 16.25 MiB inside the chunk loop, over
+# Mosaic's 16 MiB default. Every TPU generation has at least 32 MiB of
+# VMEM. Passes without them keep the default: a larger limit takes VMEM
+# from XLA's own placements of the ops around the kernel.
+_VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 
 
 def stokes_exchange_modes(gg, shapes):
@@ -283,6 +299,21 @@ def _stokes_kernel(*refs, nx, modes, mu, dt_v, dt_p, damp, dx, dy, dz,
     odVz[0] = u_dvz
 
 
+def _scope(part):
+    """The named scope ``SCOPES[part]``, a context manager."""
+    import jax
+
+    return jax.named_scope(SCOPES[part])
+
+
+def _scoped(get):
+    """A send-slab getter whose computes carry the ``slabs`` scope."""
+    def scoped_get(dim, start, size):
+        with _scope("slabs"):
+            return get(dim, start, size)
+    return scoped_get
+
+
 def stokes_step_exchange_pallas(state, gg, modes, p, *, interpret=False):
     """One fused PT iteration (all updates + the 4-field halo exchange) for
     arbitrary shardings. ``modes`` from `stokes_exchange_modes`."""
@@ -301,10 +332,10 @@ def stokes_step_exchange_pallas(state, gg, modes, p, *, interpret=False):
     from .pallas_common import all_self_exchange, self_recvs_and_ols
 
     getters = {
-        "Vx": _v_get_slab(state, p, 0),
-        "Vy": _v_get_slab(state, p, 1),
-        "Vz": _v_get_slab(state, p, 2),
-        "P": _pn_get_slab(state, p),
+        "Vx": _scoped(_v_get_slab(state, p, 0)),
+        "Vy": _scoped(_v_get_slab(state, p, 1)),
+        "Vz": _scoped(_v_get_slab(state, p, 2)),
+        "P": _scoped(_pn_get_slab(state, p)),
     }
     shapes = {"P": P.shape, "Vx": Vx.shape, "Vy": Vy.shape, "Vz": Vz.shape}
     all_self = all_self_exchange(gg, modes)
@@ -385,40 +416,40 @@ def stokes_step_exchange_pallas(state, gg, modes, p, *, interpret=False):
         mu=dtp(p.mu), dt_v=dtp(p.dt_v), dt_p=dtp(p.dt_p), damp=dtp(p.damp),
         dx=dtp(p.dx), dy=dtp(p.dy), dz=dtp(p.dz), self_ols=self_ols)
 
+    from jax.experimental.pallas import tpu as pltpu
+
+    extra = {}
     if relay:
-        from jax.experimental.pallas import tpu as pltpu
+        extra["scratch_shapes"] = [pltpu.VMEM((2, ny, nz), P.dtype),
+                                   pltpu.VMEM((2, ny, nz), Vx.dtype),
+                                   pltpu.VMEM((2, ny + 1, nz), Vy.dtype),
+                                   pltpu.VMEM((2, ny, nz + 1), Vz.dtype)]
+    recv_yz = not all_self and any(m[1] or m[2] for m in modes.values())
+    if not interpret and (relay or recv_yz):
+        extra["compiler_params"] = pltpu.CompilerParams(
+            # the relay needs the grid in order
+            dimension_semantics=("arbitrary",) if relay else None,
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES if recv_yz else None)
 
-        from .pallas_stencil import _sequential_grid_params
-
-        extra = dict(
-            scratch_shapes=[pltpu.VMEM((2, ny, nz), P.dtype),
-                            pltpu.VMEM((2, ny, nz), Vx.dtype),
-                            pltpu.VMEM((2, ny + 1, nz), Vy.dtype),
-                            pltpu.VMEM((2, ny, nz + 1), Vz.dtype)],
-            **_sequential_grid_params(interpret),  # relay needs in-order
-        )
-    else:
-        extra = {}
-
-    Pn, Vxn, Vyn, Vzn, dVxn, dVyn, dVzn = pl.pallas_call(
-        kernel,
-        grid=(nx,),
-        in_specs=in_specs,
-        out_specs=[
-            spec(cP, lambda i: (i, 0, 0)),
-            spec(cP, lambda i: (i, 0, 0)),
-            spec(cY, lambda i: (i, 0, 0)),
-            spec(cZ, lambda i: (i, 0, 0)),
-            spec(cP, lambda i: (i, 0, 0)),
-            spec(cY, lambda i: (i, 0, 0)),
-            spec(cZ, lambda i: (i, 0, 0)),
-        ],
-        out_shape=[out_shape_of(P), out_shape_of(Vx), out_shape_of(Vy),
-                   out_shape_of(Vz), out_shape_of(dVx), out_shape_of(dVy),
-                   out_shape_of(dVz)],
-        interpret=interpret,
-        **extra,
-    )(*operands)
+    with _scope("pt"):
+        Pn, Vxn, Vyn, Vzn, dVxn, dVyn, dVzn = pl.pallas_call(
+            kernel,
+            grid=(nx,),
+            in_specs=in_specs,
+            out_specs=[
+                spec(cP, lambda i: (i, 0, 0)),
+                spec(cP, lambda i: (i, 0, 0)),
+                spec(cY, lambda i: (i, 0, 0)),
+                spec(cZ, lambda i: (i, 0, 0)),
+                spec(cP, lambda i: (i, 0, 0)),
+                spec(cY, lambda i: (i, 0, 0)),
+                spec(cZ, lambda i: (i, 0, 0)),
+            ],
+            out_shape=[out_shape_of(a) for a in
+                       (P, Vx, Vy, Vz, dVx, dVy, dVz)],
+            interpret=interpret,
+            **extra,
+        )(*operands)
 
     # Vx plane nx (the kernel grid covers planes 0..nx-1): delivered like
     # the acoustic kernel's; dVx plane nx is never updated nor exchanged —
@@ -426,16 +457,17 @@ def stokes_step_exchange_pallas(state, gg, modes, p, *, interpret=False):
     from .pallas_common import vx_extra_plane_slabs, vx_extra_planes_self
     from .pallas_halo import halo_write_inplace
 
-    if all_self:
-        plane0, planeN = vx_extra_planes_self(
-            Vx, Vxn, recvs["Vx"], modes["Vx"], self_ols["Vx"], nx)
-    else:
-        plane0, planeN = vx_extra_plane_slabs(Vx, Vxn, recvs["Vx"],
-                                              modes["Vx"], nx)
-    Vxn = halo_write_inplace(Vxn, plane0, planeN, dim=0, hw=1,
-                             interpret=interpret)
-    dVxn = halo_write_inplace(
-        dVxn, lax.slice_in_dim(dVx, 0, 1, axis=0),
-        lax.slice_in_dim(dVx, nx, nx + 1, axis=0), dim=0, hw=1,
-        interpret=interpret)
+    with _scope("vx_planes"):
+        if all_self:
+            plane0, planeN = vx_extra_planes_self(
+                Vx, Vxn, recvs["Vx"], modes["Vx"], self_ols["Vx"], nx)
+        else:
+            plane0, planeN = vx_extra_plane_slabs(Vx, Vxn, recvs["Vx"],
+                                                  modes["Vx"], nx)
+        Vxn = halo_write_inplace(Vxn, plane0, planeN, dim=0, hw=1,
+                                 interpret=interpret)
+        dVxn = halo_write_inplace(
+            dVxn, lax.slice_in_dim(dVx, 0, 1, axis=0),
+            lax.slice_in_dim(dVx, nx, nx + 1, axis=0), dim=0, hw=1,
+            interpret=interpret)
     return (Pn, Vxn, Vyn, Vzn, dVxn, dVyn, dVzn, rhog)

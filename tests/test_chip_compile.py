@@ -150,15 +150,38 @@ def test_acoustic3d_runner_compiles(grid_on, dims):
            multi=gg.nprocs > 1)
 
 
-def test_stokes3d_runner_compiles(grid_on):
+@pytest.mark.parametrize("n,dims,periodic,nt_chunk", [
+    (128, (1, 1, 1), False, 2),
+    (256, (1, 1, 1), True, 200),
+    (256, (2, 2, 1), True, 100),
+], ids=["128-1chip-walls", "256-1chip-periodic", "256-2x2x1-periodic"])
+def test_stokes3d_runner_compiles(grid_on, n, dims, periodic, nt_chunk):
+    """At the benchmark cells' local size and chunk lengths: the fused
+    pass's scoped VMEM depends on the chunk loop around it."""
+    import re
+    from collections import Counter
+
     from implicitglobalgrid_tpu.models import StokesParams, make_stokes_run
 
-    n = 128
-    gg = grid_on(n, (1, 1, 1), periodic=False)
-    d = 10.0 / (n - 1)
-    p = StokesParams(mu=1.0, dt_v=d * d / 6.1 / 2.0, dt_p=6.1 / n,
-                     damp=1.0 - 6.0 / n, dx=d, dy=d, dz=d)
-    run = make_stokes_run(p, nt_chunk=2)
+    gg = grid_on(n, dims, periodic=periodic)
+    n_g = max(int(v) for v in gg.nxyz_g)
+    d = 10.0 / (n_g - 1)
+    p = StokesParams(mu=1.0, dt_v=d * d / 6.1 / 2.0, dt_p=6.1 / n_g,
+                     damp=1.0 - 6.0 / n_g, dx=d, dy=d, dz=d)
+    run = make_stokes_run(p, nt_chunk=nt_chunk)
     c, fx, fy, fz = (n, n, n), (n + 1, n, n), (n, n + 1, n), (n, n, n + 1)
     shapes = [c, fx, fy, fz, fx, fy, fz, c]
-    _check(_compiled_text(run, _sds(gg, shapes, np.float32)), multi=False)
+    txt = _compiled_text(run, _sds(gg, shapes, np.float32))
+    _check(txt, multi=gg.nprocs > 1)
+    # the device-side scope reaches the compiled kernel's metadata
+    assert any("custom-call(" in line and "igg.stokes.pt/pallas_call" in line
+               for line in txt.splitlines())
+    if gg.nprocs > 1:
+        # the 4 exchanged fields ride one permute pair (left and right)
+        # per crossing axis per step; 3 custom calls a step (the PT pass
+        # and the two extra-plane writes)
+        steps = txt.count("tpu_custom_call") // 3
+        pairs = re.findall(r"collective-permute-start\(.*"
+                           r"source_target_pairs=(\{\{[0-9,{}]*\}\})", txt)
+        crossing = sum(int(v) > 1 for v in dims)
+        assert steps and sorted(Counter(pairs).values()) == [2 * steps] * crossing
