@@ -9,7 +9,8 @@ from __future__ import annotations
 __all__ = ["slab1", "take_recvs", "add_recv_operands", "out_shape_with_vma",
            "vx_extra_plane_slabs", "deliver_recvs", "AXIS_OF",
            "shift_up", "shift_down", "shift_left", "shift_right",
-           "self_deliver", "all_self_exchange", "self_recvs_and_ols",
+           "self_deliver", "fold_z_lanes", "all_self_exchange",
+           "self_recvs_and_ols",
            "vx_extra_planes_self", "recv_kinds", "add_all_recvs"]
 
 AXIS_OF = {"x": 0, "y": 1, "z": 2}
@@ -122,17 +123,21 @@ def out_shape_with_vma(a, operands):
         return jax.ShapeDtypeStruct(a.shape, a.dtype)
 
 
-def vx_extra_plane_slabs(Vx, Vxn, recvs_vx, modes_vx, nx):
+def vx_extra_plane_slabs(Vx, Vxn, recvs_vx, modes_vx, nx, ol_z=None):
     """Final values of an x-staggered field's planes 0 and nx.
 
     The fused kernels' grid has nx programs but the field has nx+1 planes:
     plane nx is delivered (or kept raw) here, and plane 0 is rewritten with
     its final value, via the in-place dim-0 halo write. The slab patching
     preserves the z, x, y exchange order: the x recv slabs already carry z
-    corners (pipeline patching); the y recvs' corner rows go on top."""
+    corners (pipeline patching); the y recvs' corner rows go on top.
+    ``ol_z``: the field's z overlap where its self-neighbour z halo is
+    folded (`fold_z_lanes`) instead of received."""
     from jax import lax
 
     def lane_patch(plane, xpos):
+        if ol_z is not None:
+            return fold_z_lanes(plane, ol_z)
         if not modes_vx[2]:
             return plane
         zl, zr = recvs_vx[2]
@@ -185,14 +190,28 @@ def self_deliver(u, g, nx_planes, fmodes, rx, ol_y, ol_z):
     if fmodes[0] and rx is not None:
         u = jnp.where(g == 0, rx[0], jnp.where(g == nx_planes - 1, rx[1], u))
     if fmodes[2] and ol_z is not None:
-        col = lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
-        u = jnp.where(col == 0, u[:, cols - ol_z:cols - ol_z + 1], u)
-        u = jnp.where(col == cols - 1, u[:, ol_z - 1:ol_z], u)
+        u = fold_z_lanes(u, ol_z)
     if fmodes[1] and ol_y is not None:
         row = lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
         u = jnp.where(row == 0, u[rows - ol_y:rows - ol_y + 1, :], u)
         u = jnp.where(row == rows - 1, u[ol_y - 1:ol_y, :], u)
     return u
+
+
+def fold_z_lanes(u, ol_z):
+    """A self-neighbor z halo (halowidth 1) as two lane selects over the
+    last axis of a plane or slab: lane 0 <- lane ``cols-ol_z``, then lane
+    ``cols-1`` <- lane ``ol_z-1`` (`sendrecv_halo_local`); ``ol_z`` is the
+    field's z overlap, None for a field whose z does not exchange."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    if ol_z is None:
+        return u
+    cols = u.shape[-1]
+    col = lax.broadcasted_iota(jnp.int32, u.shape, u.ndim - 1)
+    u = jnp.where(col == 0, u[..., cols - ol_z:cols - ol_z + 1], u)
+    return jnp.where(col == cols - 1, u[..., ol_z - 1:ol_z], u)
 
 
 def all_self_exchange(gg, modes) -> bool:

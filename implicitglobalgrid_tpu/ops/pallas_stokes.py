@@ -21,8 +21,11 @@ Received slabs flow through the shared PACKED pipeline
 ppermute pair per mesh axis on the canonical wire schema — wire policy
 included — plus local swaps / PROC_NULL masking / per-field corner
 patching), and are delivered in the kernel's output pass in the
-reference's z, x, y order. Vx's extra face plane (and dVx's, which is not exchanged) is
-written post-kernel like the acoustic kernel's.
+reference's z, x, y order. A self-neighbor z beside crossing x/y dims
+stays out of the pipeline (`stokes_exchange_folds_z`): its halo is a pair
+of lane copies, applied to the kernel's computed planes and to every x/y
+slab the pipeline asks for. Vx's extra face plane (and dVx's, which is
+not exchanged) is written post-kernel like the acoustic kernel's.
 
 Requires the full-size face-aligned dV state of `init_stokes3d` and
 halowidth-1 grids; `stokes_exchange_modes` gates eligibility.
@@ -34,7 +37,8 @@ from functools import partial
 
 from .pallas_common import slab1 as _slab
 
-__all__ = ["SCOPES", "stokes_exchange_modes", "stokes_step_exchange_pallas"]
+__all__ = ["SCOPES", "stokes_exchange_modes", "stokes_exchange_folds_z",
+           "stokes_step_exchange_pallas"]
 
 # Device-side name scopes of the traced step (`jax.named_scope`): they reach
 # each op's HLO metadata, so a profiler trace's op events carry them in the
@@ -85,6 +89,18 @@ def stokes_exchange_modes(gg, shapes):
     # PT iteration into one pass with no deliveries (single-chip
     # non-periodic — the BASELINE bench configuration)
     return out
+
+
+def stokes_exchange_folds_z(gg, modes) -> bool:
+    """Whether the fused PT pass folds the z halo in place of exchanging
+    it: some field exchanges z as a self-neighbor (one shard, periodic,
+    displacement 1) on a grid that is not all-self (the all-self path
+    delivers every dim in the kernel already)."""
+    from .pallas_common import all_self_exchange
+
+    return (any(bool(m[2]) for m in modes.values())
+            and int(gg.dims[2]) == 1 and bool(gg.periods[2])
+            and int(gg.disp) == 1 and not all_self_exchange(gg, modes))
 
 
 def _mini_state(state, dim, lo, hi):
@@ -146,11 +162,13 @@ from .pallas_common import recv_kinds as _stokes_recv_kinds
 
 
 def _stokes_kernel(*refs, nx, modes, mu, dt_v, dt_p, damp, dx, dy, dz,
-                   self_ols=None, relay=True):
+                   self_ols=None, self_z=None, relay=True):
     """One x-plane of the fused PT iteration. Arithmetic mirrors
     `models.stokes._stokes_terms` term-for-term (same accumulation order)
     restricted to this plane; then the interior-masked dV/V updates and the
-    halo deliveries (z, x, y per field; Vx's x planes post-kernel).
+    halo deliveries (z, x, y per field; Vx's x planes post-kernel). With
+    ``self_z`` (field -> z overlap) the z halo is folded from the plane's
+    own lanes (`fold_z_lanes`) and no z recv operand is taken.
 
     Every intermediate stays at FULL plane size, positioned on a canonical
     grid and shifted with the edge-cloning operators of `pallas_common`
@@ -165,6 +183,7 @@ def _stokes_kernel(*refs, nx, modes, mu, dt_v, dt_p, damp, dx, dy, dz,
     from jax.experimental import pallas as pl
 
     from .pallas_common import deliver_recvs as _deliver
+    from .pallas_common import fold_z_lanes
     from .pallas_common import shift_down, shift_left, shift_right, shift_up
 
     it = iter(refs)
@@ -281,14 +300,15 @@ def _stokes_kernel(*refs, nx, modes, mu, dt_v, dt_p, damp, dx, dy, dz,
                             *self_ols["Vz"])
         pn = self_deliver(pnc, i, nx, modes["P"], rP["x"], *self_ols["P"])
     else:
-        u_vx = _deliver(u_vx, i, nx, modes["Vx"], None, rVx["y"], rVx["z"],
-                        ny - 1, nz - 1)
-        u_vy = _deliver(u_vy, i, nx, modes["Vy"], rVy["x"], rVy["y"],
-                        rVy["z"], ny, nz - 1)
-        u_vz = _deliver(u_vz, i, nx, modes["Vz"], rVz["x"], rVz["y"],
-                        rVz["z"], ny - 1, nz)
-        pn = _deliver(pnc, i, nx, modes["P"], rP["x"], rP["y"], rP["z"],
-                      ny - 1, nz - 1)
+        ol_z = self_z or {}
+        u_vx = _deliver(fold_z_lanes(u_vx, ol_z.get("Vx")), i, nx,
+                        modes["Vx"], None, rVx["y"], rVx["z"], ny - 1, nz - 1)
+        u_vy = _deliver(fold_z_lanes(u_vy, ol_z.get("Vy")), i, nx,
+                        modes["Vy"], rVy["x"], rVy["y"], rVy["z"], ny, nz - 1)
+        u_vz = _deliver(fold_z_lanes(u_vz, ol_z.get("Vz")), i, nx,
+                        modes["Vz"], rVz["x"], rVz["y"], rVz["z"], ny - 1, nz)
+        pn = _deliver(fold_z_lanes(pnc, ol_z.get("P")), i, nx, modes["P"],
+                      rP["x"], rP["y"], rP["z"], ny - 1, nz - 1)
 
     oP[0] = pn
     oVx[0] = u_vx
@@ -306,11 +326,14 @@ def _scope(part):
     return jax.named_scope(SCOPES[part])
 
 
-def _scoped(get):
-    """A send-slab getter whose computes carry the ``slabs`` scope."""
+def _scoped(get, ol_z=None):
+    """A send-slab getter whose computes carry the ``slabs`` scope; with
+    ``ol_z`` each slab takes the field's folded z halo (`fold_z_lanes`)."""
+    from .pallas_common import fold_z_lanes
+
     def scoped_get(dim, start, size):
         with _scope("slabs"):
-            return get(dim, start, size)
+            return fold_z_lanes(get(dim, start, size), ol_z)
     return scoped_get
 
 
@@ -331,13 +354,22 @@ def stokes_step_exchange_pallas(state, gg, modes, p, *, interpret=False):
 
     from .pallas_common import all_self_exchange, self_recvs_and_ols
 
-    getters = {
-        "Vx": _scoped(_v_get_slab(state, p, 0)),
-        "Vy": _scoped(_v_get_slab(state, p, 1)),
-        "Vz": _scoped(_v_get_slab(state, p, 2)),
-        "P": _scoped(_pn_get_slab(state, p)),
-    }
     shapes = {"P": P.shape, "Vx": Vx.shape, "Vy": Vy.shape, "Vz": Vz.shape}
+    fold = {}  # field -> z overlap, where the z halo is folded
+    if stokes_exchange_folds_z(gg, modes):
+        # a self-neighbor z stays out of the pipeline: every x/y slab
+        # (sends and PROC_NULL current halos) takes the z lane copy first,
+        # which is what patching it with the z recvs gives, since z comes
+        # first; the kernel folds its computed planes the same way
+        fold = {f: int(gg.overlaps[2]) + int(s[2]) - int(gg.nxyz[2])
+                for f, s in shapes.items() if modes[f][2]}
+        modes = {f: (m[0], m[1], False) for f, m in modes.items()}
+    getters = {
+        "Vx": _scoped(_v_get_slab(state, p, 0), fold.get("Vx")),
+        "Vy": _scoped(_v_get_slab(state, p, 1), fold.get("Vy")),
+        "Vz": _scoped(_v_get_slab(state, p, 2), fold.get("Vz")),
+        "P": _scoped(_pn_get_slab(state, p), fold.get("P")),
+    }
     all_self = all_self_exchange(gg, modes)
     self_ols = None
     if all_self:
@@ -414,7 +446,8 @@ def stokes_step_exchange_pallas(state, gg, modes, p, *, interpret=False):
         _stokes_kernel, nx=nx, relay=relay,
         modes={k: tuple(bool(b) for b in v) for k, v in modes.items()},
         mu=dtp(p.mu), dt_v=dtp(p.dt_v), dt_p=dtp(p.dt_p), damp=dtp(p.damp),
-        dx=dtp(p.dx), dy=dtp(p.dy), dz=dtp(p.dz), self_ols=self_ols)
+        dx=dtp(p.dx), dy=dtp(p.dy), dz=dtp(p.dz), self_ols=self_ols,
+        self_z=fold)
 
     from jax.experimental.pallas import tpu as pltpu
 
@@ -463,7 +496,8 @@ def stokes_step_exchange_pallas(state, gg, modes, p, *, interpret=False):
                 Vx, Vxn, recvs["Vx"], modes["Vx"], self_ols["Vx"], nx)
         else:
             plane0, planeN = vx_extra_plane_slabs(Vx, Vxn, recvs["Vx"],
-                                                  modes["Vx"], nx)
+                                                  modes["Vx"], nx,
+                                                  fold.get("Vx"))
         Vxn = halo_write_inplace(Vxn, plane0, planeN, dim=0, hw=1,
                                  interpret=interpret)
         dVxn = halo_write_inplace(

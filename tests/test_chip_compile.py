@@ -103,6 +103,22 @@ def _assert_z_folded(txt, n):
             rf"= f32\[{n},{n},{n}\]\{{1,0,2\b[^}}]*\}} copy\(", line)
 
 
+def _assert_stokes_z_folded(txt, n):
+    """The PT Stokes analog of `_assert_z_folded`: on a 2x2x1 mesh the
+    fused pass folds the self-neighbor z halo of P, Vx, Vy and Vz into the
+    kernel and the x/y send slabs, so no array of the program is a
+    lane-sparse z window over the x-y extent (the mini-state slab computes
+    and the concatenated z recvs, minor dim 1-4), and the PT kernel takes
+    no (.., .., 2) z operand."""
+    import re
+
+    xy = rf"{n}|{n + 1}"
+    assert not re.search(rf"f32\[(?:{xy}),(?:{xy}),[1-4]\]", txt)
+    for line in txt.splitlines():
+        if "tpu_custom_call" in line and "igg.stokes.pt/" in line:
+            assert not re.search(r"f32\[\d+,\d+,2\]", line)
+
+
 @pytest.mark.parametrize("n,dims,dtype", [
     (256, (1, 1, 1), np.float32),
     (256, (1, 1, 1), "bfloat16"),
@@ -185,3 +201,5 @@ def test_stokes3d_runner_compiles(grid_on, n, dims, periodic, nt_chunk):
                            r"source_target_pairs=(\{\{[0-9,{}]*\}\})", txt)
         crossing = sum(int(v) > 1 for v in dims)
         assert steps and sorted(Counter(pairs).values()) == [2 * steps] * crossing
+    if dims == (2, 2, 1):
+        _assert_stokes_z_folded(txt, n)

@@ -431,6 +431,35 @@ def test_fused_step_exchange_self_z_permutes():
     assert not z_slabs, z_slabs[:3]
 
 
+def test_fused_stokes_self_z_permutes():
+    """The fused PT Stokes pass on 2x2x1 periodic: z is a self-neighbor
+    axis folded into the kernel and the x/y send slabs
+    (`stokes_exchange_folds_z`), so the 4 exchanged fields ride one packed
+    permute pair per crossing axis — 4 permutes on gx/gy, byte-exact to the
+    plan — and no op makes or takes a z slab over the x-y extent (the
+    mini-state windows and the z recvs of P, Vx, Vy and Vz)."""
+    from implicitglobalgrid_tpu.analysis import (
+        axis_routes, measure_axes, model_contract,
+    )
+    from implicitglobalgrid_tpu.models import init_stokes3d, make_stokes_run
+
+    igg.init_global_grid(8, 8, 16, dimx=2, dimy=2, dimz=1,
+                         periodx=1, periody=1, periodz=1, quiet=True)
+    state, p = init_stokes3d(dtype=np.float32)
+    ir = parse_program(make_stokes_run(p, 1, impl="pallas_interpret"),
+                       *state)
+    _assert_fused(ir, (8, 8, 16), 4)
+    _assert_honors(ir, model_contract("stokes3d", state, impl="pallas"))
+    axes = measure_axes(ir, axis_routes())
+    assert {a: r["permutes"] for a, r in axes.items()} == {"gx": 2, "gy": 2}
+    xy = {(8, 8), (9, 8), (8, 9)}
+    z_slabs = [o.line for o in ir.find(dtype="f32")
+               if any(s.dims[:2] in xy and s.dims[2:] < (5,)
+                      for s in o.shapes + o.operand_shapes
+                      if len(s.dims) == 3)]
+    assert not z_slabs, z_slabs[:3]
+
+
 def test_fused_step_all_self_emits_no_collectives():
     """All-self mesh: the fused step (multi-plane kernel + in-kernel halo
     fusion) must emit NO collectives at all."""

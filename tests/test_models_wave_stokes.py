@@ -185,22 +185,37 @@ def test_acoustic_pallas_window_handoff_matches_xla(monkeypatch):
     ((2, 2, 2), (0, 0, 0), "all multi-shard PROC_NULL edges"),
     ((2, 2, 2), (1, 1, 1), "all multi-shard periodic"),
     ((1, 2, 4), (1, 0, 1), "self x + PROC_NULL y + 4-shard z"),
+    ((2, 2, 1), (1, 1, 1), "2x2 periodic + self z folded"),
+    ((2, 2, 1), (0, 0, 1), "2x2 PROC_NULL x/y + self z folded"),
+    ((2, 1, 1), (1, 1, 1), "2-shard x + self y swapped + self z folded"),
+    ((1, 2, 1), (0, 1, 1), "no x exchange + 2-shard y + self z folded"),
 ])
 def test_stokes_pallas_fused_matches_xla(dims, periods, label):
     """The fused Stokes Pallas pass (all PT updates + 4-field exchange in
     ONE kernel, `ops/pallas_stokes.py`) must reproduce the XLA step +
-    sequential exchanges over a multi-iteration run."""
-    from implicitglobalgrid_tpu.ops.pallas_stokes import stokes_exchange_modes
+    sequential exchanges over a multi-iteration run. A self-neighbor z
+    beside a crossing dim is folded into the kernel and the x/y slabs (and
+    Vx's extra plane where x does not exchange). Every field starts random,
+    so every halo and boundary plane carries data from the first step."""
+    from implicitglobalgrid_tpu.ops.pallas_stokes import (
+        stokes_exchange_folds_z, stokes_exchange_modes,
+    )
 
     igg.init_global_grid(8, 8, 16, dimx=dims[0], dimy=dims[1], dimz=dims[2],
                          periodx=periods[0], periody=periods[1],
                          periodz=periods[2], quiet=True)
     gg = igg.global_grid()
     state, p = init_stokes3d(dtype=np.float32)
+    rng = np.random.default_rng(7)
+    state = tuple(igg.device_put_g(
+        rng.uniform(-1, 1, a.shape).astype(np.float32)) for a in state)
     shapes = tuple(
         tuple(int(s) // int(gg.dims[d]) for d, s in enumerate(a.shape))
         for a in state)
-    assert stokes_exchange_modes(gg, shapes) is not None, label
+    modes = stokes_exchange_modes(gg, shapes)
+    assert modes is not None, label
+    folds = dims[2] == 1 and periods[2] == 1 and dims[:2] != (1, 1)
+    assert stokes_exchange_folds_z(gg, modes) == folds, label
     a = run_stokes(state, p, 4, nt_chunk=2, impl="xla")
     b = run_stokes(state, p, 4, nt_chunk=2, impl="pallas_interpret")
     names = ("P", "Vx", "Vy", "Vz", "dVx", "dVy", "dVz", "rhog")
