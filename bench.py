@@ -119,21 +119,6 @@ def main() -> None:
         if last is not None and last["method"] != "two-point":
             notes[name + "_method"] = last["method"]
 
-    from contextlib import contextmanager
-
-    @contextmanager
-    def _env0(var):
-        """Force ONE variant env var to 0, restoring it afterwards."""
-        old = os.environ.get(var)
-        os.environ[var] = "0"
-        try:
-            yield
-        finally:
-            if old is None:
-                os.environ.pop(var, None)
-            else:
-                os.environ[var] = old
-
     def part(name, fn):
         """One config: its rate, or the exception that fails the run."""
         bench_util.two_point.last = None  # per-config method attribution
@@ -144,30 +129,6 @@ def main() -> None:
     nx, nt = (64, 10) if cpu else (256, 600)
     part("headline", lambda: _rate3(nx, nt, np.float32))
     headline = configs.pop("headline")
-
-    # A/B pair for the round-4 window handoff (hardware only): the same
-    # config with IGG_MP_HANDOFF=0 runs the pre-handoff pipeline that
-    # re-DMAs the 2 overlap planes per window — the traffic model predicts
-    # rate ratio (3 + 2/P)/3, and the measured pair either confirms the
-    # model or falsifies it in the committed artifact.  The off-leg runs
-    # ONLY when the headline actually exercised the handoff tier — with
-    # IGG_USE_PALLAS=0 or an ineligible shape the two legs are the
-    # identical program, and a ~1.0 ratio would falsely "falsify" the
-    # model (and burn a full hardware measurement for nothing).
-    def _handoff_active():
-        import jax as _jax
-
-        from implicitglobalgrid_tpu.ops.pallas_stencil import mp_handoff
-        return (os.environ.get("IGG_USE_PALLAS", "1") != "0"
-                and bool(mp_handoff(_jax.ShapeDtypeStruct(
-                    (nx, nx, nx), np.float32))))
-
-    if not cpu and _handoff_active():
-        def _rate3_handoff_off():
-            with _env0("IGG_MP_HANDOFF"):
-                return _rate3(nx, nt, np.float32)
-
-        part("diffusion3D_f32_handoff_off", _rate3_handoff_off)
 
     # roofline accounting for the headline row (multi-plane fused kernel:
     # T read 1.0x with the VMEM window handoff else (1+2/P)x, + Cp read
@@ -280,18 +241,6 @@ def main() -> None:
         notes["stokes3D_pt_f32"] = _INTERPRET_SKIP
     else:
         part("stokes3D_pt_f32", lambda: _rate_stokes("pallas"))
-
-        # A/B pair for the round-4 plane relay: IGG_PLANE_RELAY=0 re-reads
-        # each field's [i-1] plane from HBM (15 read streams + 7 writes =
-        # 22 passes vs 18 with the relay — predicted ratio 22/18).
-        # Skipped when the env already disables the relay: both legs
-        # would run the identical program and fake a ~1.0 ratio.
-        def _rate_stokes_relay_off():
-            with _env0("IGG_PLANE_RELAY"):
-                return _rate_stokes("pallas")
-
-        if os.environ.get("IGG_PLANE_RELAY", "1") != "0":
-            part("stokes3D_pt_relay_off_f32", _rate_stokes_relay_off)
     notes["kernel_tier"] = (
         "acoustic3D_pallas_fused_f32 / stokes3D_pt_f32 run the fused "
         "Pallas passes (pallas_wave/pallas_stokes; rate rows are "
@@ -345,24 +294,6 @@ def main() -> None:
         "docstring")
     pct_meas = 100.0 * effective_gbps / configs["hbm_triad_GBps"]
 
-    # A/B variant deltas vs the traffic-model predictions (round-4
-    # verdict: the measured ratio must confirm the 3+2/P -> 3.0 model)
-    ab = {}
-    off = configs.get("diffusion3D_f32_handoff_off")
-    if off:
-        ab["window_handoff"] = {
-            "measured_ratio": headline / off,
-            "predicted_ratio": (3.0 + 2.0 / P) / 3.0,
-            "note": "headline (handoff on) / IGG_MP_HANDOFF=0",
-        }
-    s_on = configs.get("stokes3D_pt_f32")
-    s_off = configs.get("stokes3D_pt_relay_off_f32")
-    if s_on and s_off:
-        ab["plane_relay_stokes"] = {
-            "measured_ratio": s_on / s_off,
-            "predicted_ratio": 22.0 / 18.0,
-            "note": "stokes fused (relay on) / IGG_PLANE_RELAY=0",
-        }
     if pct_peak is not None and pct_peak > 100:
         notes["roofline"] = (
             "pct_hbm_peak>100 against the NOMINAL datasheet peak: compare "
@@ -388,7 +319,6 @@ def main() -> None:
         "pct_hbm_peak": pct_peak,
         "pct_hbm_measured": pct_meas,
         "configs": configs,
-        "variant_ab": ab or None,
         "pallas_check": pallas_check,
         "notes": notes or None,
     })

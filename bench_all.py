@@ -150,21 +150,6 @@ def main() -> None:
         "speedup_8fields": speed8,
     }))
 
-    # --- topology-staged wire (ISSUE 16) -----------------------------------
-    # z exchange re-routed ICI leader-gather -> ONE striped DCN transfer
-    # per granule pair -> ICI scatter, on a two-granule mesh: the static
-    # per-DCN-link message-count fold (`staged_dcn_msgs_ratio`, gated
-    # absolute >= devices-per-granule/2 under IGG_BENCH_STRICT), the
-    # measured staging-overhead A/B, and the modeled speedup on the
-    # hierarchical ICI+DCN profile. Config owned by
-    # `bench_halo.run_staged_ab` (shared with the standalone bench).
-    staged_rows = bench_halo.run_staged_ab(dims3, cpu)
-    for row in staged_rows:
-        results.append(bench_util.emit(row))
-    staged_ok = all(
-        r["value"] >= 1.0 for r in staged_rows
-        if r["metric"] == "staged_msgs_gate_ok" and r["value"] is not None)
-
     # --- ensemble axis: per-member step vs solo at E=4/8/16 (ISSUE 12) -----
     # one vmapped chunk advances E scenario members behind the SAME
     # collectives; per-member speedup rows ride the perfdb gate and two
@@ -405,7 +390,7 @@ def main() -> None:
     lint_failed = not ruff_missing and lint.returncode != 0
     if (not gate["ok"] or lint_failed or not coalesce8_ok
             or not ensemble_ok or not tuned_ok or not reshard_ok
-            or not staged_ok or not serve_ok or not live_ok
+            or not serve_ok or not live_ok
             or not autoscale_ok or not tracing_ok) \
             and os.environ.get("IGG_BENCH_STRICT") == "1":
         sys.exit(1)

@@ -168,161 +168,6 @@ def run_coalescing_ab(dims, cpu: bool):
         igg.finalize_global_grid()
 
 
-def staged_ab_rows(nx: int, c1: int, devices_per_granule: int,
-                   n_fields: int = 2):
-    """Topology-staged wire rows on the CURRENT two-granule grid (ISSUE
-    16; caller owns init/finalize and the IGG_TPU_DCN_GRANULES scope):
-
-    - ``staged_dcn_msgs_ratio`` — static, from `halo_comm_plan`'s staged
-      detail: flat per-DCN-link message count / staged (= the ICI gather
-      fold). Gated absolute >= devices_per_granule/2 under
-      IGG_BENCH_STRICT (``staged_msgs_gate_ok``).
-    - ``update_halo_staged_vs_flat_speedup`` — measured flat/staged loop
-      seconds. The emulated CPU mesh has no DCN to save, so this is the
-      staging-overhead gate in disguise; the modeled row prices the win.
-    """
-    import numpy as np
-
-    import implicitglobalgrid_tpu as igg
-    from implicitglobalgrid_tpu.models.common import make_state_runner
-
-    fields = tuple(igg.ones_g((nx, nx, nx), np.float32) * (i + 1)
-                   for i in range(n_fields))
-    plan = igg.halo_comm_plan(*fields, wire_stage="z:staged")
-    det = plan["axes"].get("gz", {}).get("staged")
-    if det is None:
-        return [{
-            "metric": "staged_dcn_msgs_ratio", "value": None,
-            "note": "no staged layout on this mesh (z granules "
-                    "undeclared or no perpendicular ICI gather axis); "
-                    "rows skipped",
-        }]
-    ratio = det["flat_dcn_pairs"] / det["dcn_pairs"]
-    secs = {}
-    for mode, ws in (("flat", None), ("staged", "z:staged")):
-        def step(s, ws=ws):
-            out = igg.local_update_halo(*s, wire_stage=ws)
-            return out if isinstance(out, tuple) else (out,)
-
-        def chunk(c):
-            run = make_state_runner(
-                step, (3,) * n_fields, nt_chunk=c,
-                key=("bench_halo_staged", mode, n_fields, nx))
-            igg.sync(run(*fields))
-
-        secs[mode] = bench_util.two_point(chunk, c1, 3 * c1, reps=4)
-    gate = ratio >= devices_per_granule / 2.0
-    return [
-        {
-            "metric": "staged_dcn_msgs_ratio",
-            "value": ratio,
-            "unit": "x (flat DCN-crossing pairs / staged, per round — "
-                    "the per-DCN-link message-count fold)",
-            "dcn_pairs": det["dcn_pairs"],
-            "flat_dcn_pairs": det["flat_dcn_pairs"],
-            "fold": det["fold"],
-            "gather_axis": det["gather_axis"],
-        },
-        {
-            "metric": "staged_msgs_gate_ok",
-            "value": 1.0 if gate else 0.0,
-            "unit": f"bool (1 = fold >= devices_per_granule/2 = "
-                    f"{devices_per_granule / 2.0:g})",
-        },
-        {
-            "metric": "update_halo_staged_vs_flat_speedup",
-            "value": secs["flat"] / secs["staged"],
-            "unit": "x (flat_s / staged_s per exchange-loop call)",
-            "flat_s_per_call": secs["flat"],
-            "staged_s_per_call": secs["staged"],
-            "note": "the emulated CPU mesh has no DCN link to save: this "
-                    "is the staging-overhead gate; staged_model_speedup "
-                    "prices the on-wire win",
-        },
-    ]
-
-
-def staged_model_row(dims_s):
-    """The staged-vs-flat step speedup, MODELED (`predict_step` —
-    deterministic): diffusion3D at production-scale blocks on the canned
-    hierarchical ICI+DCN profile (`hierarchical_machine_profile` — the
-    COMM_AVOID.json regime), z staged over 2 granules. The caller scopes
-    IGG_TPU_DCN_GRANULES; nothing is allocated."""
-    import jax
-    import numpy as np
-
-    import implicitglobalgrid_tpu as igg
-    from implicitglobalgrid_tpu.telemetry.perfmodel import (
-        hierarchical_machine_profile,
-    )
-
-    profile = hierarchical_machine_profile()
-    nx = 256
-    igg.init_global_grid(nx, nx, nx, dimx=dims_s[0], dimy=dims_s[1],
-                         dimz=dims_s[2], periodx=1, periody=1, periodz=1,
-                         quiet=True)
-    try:
-        stacked = tuple(nx * d for d in dims_s)
-        T = jax.ShapeDtypeStruct(stacked, np.float32)
-        Cp = jax.ShapeDtypeStruct(stacked, np.float32)
-        flat = igg.predict_step("diffusion3d", (T, Cp), profile=profile)
-        staged = igg.predict_step("diffusion3d", (T, Cp), profile=profile,
-                                  wire_stage="z:staged")
-        verdict = staged["comm"].get("gz", {}).get("staged", {})
-        return {
-            "metric": "staged_model_speedup",
-            "value": flat["step_s"] / staged["step_s"],
-            "unit": "x (flat step_s / staged step_s, modeled on the "
-                    "hierarchical ICI+DCN profile)",
-            "flat_step_s": flat["step_s"],
-            "staged_step_s": staged["step_s"],
-            "staged_axis_wins": bool(verdict.get("wins", False)),
-            "staged_axis_s": verdict.get("staged_s"),
-            "flat_axis_s": verdict.get("flat_s"),
-        }
-    finally:
-        igg.finalize_global_grid()
-
-
-def run_staged_ab(dims, cpu: bool):
-    """The topology-staged wire leg (ISSUE 16) on a TWO-GRANULE mesh: z
-    split into 2 DCN granules (scoped ``IGG_TPU_DCN_GRANULES=z:2``) with
-    the remaining devices forming the perpendicular ICI gather axis.
-    Shared by this script's __main__ and `bench_all.py` so the config
-    stays in ONE place."""
-    import os
-
-    import implicitglobalgrid_tpu as igg
-
-    nd = dims[0] * dims[1] * dims[2]
-    if nd < 4:
-        return [{
-            "metric": "staged_dcn_msgs_ratio", "value": None,
-            "note": f"{nd} device(s) cannot form a two-granule mesh with "
-                    "an ICI gather axis; rows skipped",
-        }]
-    dims_s = (nd // 2, 1, 2)  # z = the DCN axis, x = the gather axis
-    nx_ab, c_ab = (32, 8) if cpu else (256, 20)
-    saved = os.environ.get("IGG_TPU_DCN_GRANULES")
-    os.environ["IGG_TPU_DCN_GRANULES"] = "z:2"
-    try:
-        igg.init_global_grid(nx_ab, nx_ab, nx_ab, dimx=dims_s[0],
-                             dimy=dims_s[1], dimz=dims_s[2], periodx=1,
-                             periody=1, periodz=1, quiet=True)
-        try:
-            rows = staged_ab_rows(nx_ab, c_ab,
-                                  devices_per_granule=nd // 2)
-        finally:
-            igg.finalize_global_grid()
-        rows.append(staged_model_row(dims_s))
-    finally:
-        if saved is None:
-            os.environ.pop("IGG_TPU_DCN_GRANULES", None)
-        else:
-            os.environ["IGG_TPU_DCN_GRANULES"] = saved
-    return rows
-
-
 def main() -> None:
     cpu = "--cpu" in sys.argv
     if cpu:
@@ -380,11 +225,6 @@ def main() -> None:
     # Coalesced vs per-field A/B (2/4/8 fields) on its own grid — the
     # multi-field leg `bench_all.py` also records into BENCH_ALL.json.
     for row in run_coalescing_ab(dims, cpu):
-        bench_util.emit(row)
-
-    # Topology-staged wire A/B + modeled speedup on a two-granule mesh
-    # (ISSUE 16) — also recorded by `bench_all.py`.
-    for row in run_staged_ab(dims, cpu):
         bench_util.emit(row)
 
 

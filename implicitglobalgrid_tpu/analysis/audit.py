@@ -230,7 +230,7 @@ def _rounds_impl(model: str, impl: str, fields) -> str:
 
 
 def audit_model(model: str, *, impl: str = "xla", dtype=None,
-                wire_dtype=None, wire_stage=None, lints=None,
+                wire_dtype=None, lints=None,
                 crosscheck: bool = True,
                 optimized: bool = True,
                 ensemble: int | None = None,
@@ -261,13 +261,6 @@ def audit_model(model: str, *, impl: str = "xla", dtype=None,
     ``meta["lowered_for_wire_audit"]`` records the switch — so the
     documented CLI gate never false-fails a healthy program.
 
-    ``wire_stage`` audits the TOPOLOGY-STAGED program (the
-    `ops.wire.resolve_wire_stage` spelling family, e.g. ``"z:staged"``):
-    applied to both sides like ``wire_dtype`` — the compile (scoped
-    ``IGG_HALO_WIRE_STAGE``, restored after) and the expectation (the
-    staged multi-stage contract from `model_contract(wire_stage=)` plus
-    the crosscheck's staged pricing).
-
     ``comm_every`` (a deep per-axis cadence — int / ``"z:2,x:1"`` /
     dict) audits the DEEP-HALO SUPER-STEP program instead of the plain
     step: the compiled cycle's per-axis permute counts and k_d-wide
@@ -295,17 +288,7 @@ def audit_model(model: str, *, impl: str = "xla", dtype=None,
         ensemble = int(ensemble)
         meta["ensemble"] = ensemble
     saved_wire = os.environ.get("IGG_HALO_WIRE_DTYPE")
-    saved_stage = os.environ.get("IGG_HALO_WIRE_STAGE")
     try:
-        if wire_stage is not None:
-            from ..ops.wire import resolve_wire_stage
-
-            stage_policy = resolve_wire_stage(wire_stage)
-            # canonical spelling round-trip, same contract as wire_dtype:
-            # the runners resolve staging from the env var at trace time
-            os.environ["IGG_HALO_WIRE_STAGE"] = (
-                "off" if stage_policy is None else str(stage_policy))
-            meta["wire_stage"] = os.environ["IGG_HALO_WIRE_STAGE"]
         if wire_dtype is not None:
             from ..ops.precision import resolve_wire_dtype
 
@@ -336,10 +319,6 @@ def audit_model(model: str, *, impl: str = "xla", dtype=None,
             os.environ.pop("IGG_HALO_WIRE_DTYPE", None)
         else:
             os.environ["IGG_HALO_WIRE_DTYPE"] = saved_wire
-        if saved_stage is None:
-            os.environ.pop("IGG_HALO_WIRE_STAGE", None)
-        else:
-            os.environ["IGG_HALO_WIRE_STAGE"] = saved_stage
     from ..telemetry.perfmodel import STEP_WORKLOADS
 
     rounds_impl = impl if cad.deep else _rounds_impl(model, impl, fields)
@@ -352,8 +331,7 @@ def audit_model(model: str, *, impl: str = "xla", dtype=None,
     if model in STEP_WORKLOADS:
         contract = model_contract(model, fields, wire_dtype=wire_dtype,
                                   impl=rounds_impl, ensemble=ensemble,
-                                  comm_every=comm_every,
-                                  wire_stage=wire_stage)
+                                  comm_every=comm_every)
     cfg = default_lint_config(
         state_dtypes={str(np.dtype(getattr(f, "dtype", "float32")))
                       for f in fields},
@@ -365,8 +343,7 @@ def audit_model(model: str, *, impl: str = "xla", dtype=None,
         cc = perfmodel_crosscheck(model, fields, ir,
                                   wire_dtype=wire_dtype, impl=rounds_impl,
                                   ensemble=ensemble,
-                                  comm_every=comm_every,
-                                  wire_stage=wire_stage)
+                                  comm_every=comm_every)
     if cc is None:
         return rep
     return AuditReport(

@@ -182,31 +182,6 @@ def axis_routes(gg=None) -> dict:
                 routes.append(frozenset(pairs))
         if routes:
             table[axis] = tuple(routes)
-    # topology-staged sub-routes: when the grid declares DCN granules
-    # along an axis (`GlobalGrid.dcn_granules`), the staged wire's
-    # gather / striped-DCN / scatter / intra hops ride pair-sets of
-    # their own — appended under the staged axis so a staged program's
-    # permutes attribute. A gather route that coincides with the gather
-    # axis's flat route (every shard crosses, block=1) attributes to the
-    # GATHER axis by first-match order — exactly the link its traffic
-    # crosses, and the same order `_merged_plan` derives contracts with.
-    from ..parallel.topology import staged_wire_layout
-
-    for d, axis in enumerate(AXIS_NAMES):
-        lay = staged_wire_layout(gg, d)
-        if lay is None:
-            continue
-        have = {fs for rts in table.values() for fs in rts}
-        extra = []
-        for dr in lay.directions:
-            for pl in (dr.gather_pairs, dr.dcn_pairs, dr.scatter_pairs,
-                       dr.intra_pairs_lin):
-                fs = frozenset((int(s), int(t)) for s, t in pl if s != t)
-                if fs and fs not in have:
-                    have.add(fs)
-                    extra.append(fs)
-        if extra:
-            table[axis] = tuple(table.get(axis, ())) + tuple(extra)
     return table
 
 
@@ -259,21 +234,8 @@ def hlo_dtype(name) -> str:
     return _NP_TO_HLO.get(str(name), str(name))
 
 
-def _staged_stage_routes(layout) -> dict:
-    """``{(direction, stage): pair tuple}`` of one `StagedWireLayout` —
-    the route each stage-table entry's ppermutes ride."""
-    out = {}
-    for dr in layout.directions:
-        out[(dr.name, "intra")] = dr.intra_pairs_lin
-        out[(dr.name, "gather")] = dr.gather_pairs
-        out[(dr.name, "dcn")] = dr.dcn_pairs
-        out[(dr.name, "scatter")] = dr.scatter_pairs
-    return out
-
-
 def _merged_plan(fields, rounds, *, dims=None, coalesce=None,
-                 wire_dtype=None, ensemble=None, comm_every=None,
-                 wire_stage=None) -> dict:
+                 wire_dtype=None, ensemble=None, comm_every=None) -> dict:
     """Per-axis {ppermutes, wire_bytes, dtypes} merged over the exchange
     rounds exactly as `telemetry.predict_step` merges them: fields in one
     round coalesce, separate rounds pay separate permutes.
@@ -293,20 +255,10 @@ def _merged_plan(fields, rounds, *, dims=None, coalesce=None,
     each round only along the axes due at each sub-step
     (`CommCadence.due_dims` — the `models.*.deep_step` schedule), so the
     merged totals are per SUPER-STEP program: axis ``d`` carries
-    ``cycle / k_d`` exchanges of its ``depth*k_d``-wide slabs.
-
-    ``wire_stage`` merges the topology-staged program: a staged axis's
-    plan record carries the hierarchical stage table (absolute ops /
-    bytes — the per-line scaling does not apply), and each stage's ops
-    are attributed through `attribute_axis` over the SAME route table
-    `check_contract` measures with — so a gather pipeline whose route
-    coincides with the gather axis's flat route counts under THAT axis,
-    exactly as the parser will count it."""
+    ``cycle / k_d`` exchanges of its ``depth*k_d``-wide slabs."""
     from ..ops.halo import halo_comm_plan
     from ..ops.wire import resolve_comm_every
-    from ..parallel.topology import (
-        AXIS_NAMES, global_grid, staged_wire_layout,
-    )
+    from ..parallel.topology import AXIS_NAMES, global_grid
 
     gg = global_grid()
     gdims = [int(d) for d in gg.dims]
@@ -323,8 +275,6 @@ def _merged_plan(fields, rounds, *, dims=None, coalesce=None,
         events = [e for e in events if e]
     else:
         events = [dims]
-    table = axis_routes(gg)
-    stage_routes: dict = {}
     merged: dict = {}
 
     def rec_for(axis):
@@ -338,26 +288,9 @@ def _merged_plan(fields, rounds, *, dims=None, coalesce=None,
                     f"{len(fields)} given fields.")
             sub = halo_comm_plan(*(fields[i] for i in group), dims=ev_dims,
                                  coalesce=coalesce, wire_dtype=wire_dtype,
-                                 ensemble=ensemble, wire_stage=wire_stage)
+                                 ensemble=ensemble)
             for axis, rec in sub["axes"].items():
                 dts = tuple(hlo_dtype(d) for d in rec["by_dtype"])
-                if "staged" in rec:
-                    # hierarchical stage table: absolute ops/bytes (no
-                    # per-line scaling), each stage counted on the axis
-                    # its ROUTE attributes to — byte-identical to what
-                    # the parser measures on the compiled program
-                    d = axis_dim[axis]
-                    if d not in stage_routes:
-                        stage_routes[d] = _staged_stage_routes(
-                            staged_wire_layout(gg, d))
-                    for st in rec["staged"]["stages"]:
-                        pl = stage_routes[d][(st["direction"], st["stage"])]
-                        ax = attribute_axis(table, pl)
-                        dst = rec_for(ax if ax is not None else axis)
-                        dst["permutes"] += int(st["ops"])
-                        dst["wire_bytes"] += int(st["wire_bytes"])
-                        dst["dtypes"].update(dts)
-                    continue
                 n_lines = total // gdims[axis_dim[axis]]
                 dst = rec_for(axis)
                 dst["permutes"] += int(rec["ppermutes"])
@@ -384,7 +317,7 @@ def _local_block_cells(fields) -> int:
 def exchange_contract(*fields, rounds=None, dims=None, coalesce=None,
                       wire_dtype=None, guard_floats: int | None = None,
                       ensemble: int | None = None, comm_every=None,
-                      wire_stage=None, meta=None) -> CollectiveContract:
+                      meta=None) -> CollectiveContract:
     """Derive the contract for an exchange (or a step program) over the
     CURRENT grid from the static wire plan alone.
 
@@ -406,16 +339,7 @@ def exchange_contract(*fields, rounds=None, dims=None, coalesce=None,
     local block (a batched payload legitimately aggregates every
     member's slabs), and ``guard_floats`` stays the PER-MEMBER float
     count: the expected psum payload scales to ``f32[E·guard_floats]``
-    exactly like `guard_contract`.
-
-    ``wire_stage`` (the `ops.wire.resolve_wire_stage` spelling family)
-    derives the TOPOLOGY-STAGED program's contract: a staged axis's
-    expectations prove the hierarchical pipeline byte-exactly — per-stage
-    permute counts (``fold - 1`` gather + 1 striped DCN + ``fold - 1``
-    scatter per cross direction, plus any intra pair), each stage's ops
-    counted on the mesh axis its ROUTE attributes to, and exactly
-    ``dcn_pairs`` DCN-crossing transfers per round (ONE per granule-pair
-    per direction)."""
+    exactly like `guard_contract`."""
     from ..parallel.topology import check_initialized, global_grid
 
     check_initialized()
@@ -430,42 +354,31 @@ def exchange_contract(*fields, rounds=None, dims=None, coalesce=None,
     rounds = rounds if rounds is not None else (tuple(range(len(fields))),)
     merged = _merged_plan(fields, rounds, dims=dims, coalesce=coalesce,
                           wire_dtype=wire_dtype, ensemble=ensemble,
-                          comm_every=comm_every, wire_stage=wire_stage)
+                          comm_every=comm_every)
     axes = {a: {"permutes": r["permutes"], "wire_bytes": r["wire_bytes"],
                 "dtypes": tuple(sorted(r["dtypes"]))}
             for a, r in merged.items() if r["permutes"]}
-    from ..ops.wire import resolve_comm_every, resolve_wire_stage
+    from ..ops.wire import resolve_comm_every
 
     cad = resolve_comm_every(comm_every if comm_every is not None else 1)
-    stg = resolve_wire_stage(wire_stage)
-    # a staged DCN stripe legitimately aggregates fold x the packed
-    # payload — widen the structural slab bound by the largest fold
-    bound = _local_block_cells(fields) * E
-    if stg is not None:
-        from ..parallel.topology import staged_wire_layout
-
-        folds = [staged_wire_layout(gg, d) for d in stg.staged_dims]
-        fold = max((int(l.fold) for l in folds if l is not None), default=1)
-        bound *= fold
     return CollectiveContract(
         axes=axes,
         routes=axis_routes(gg),
         allreduces=0 if guard_floats is None else 1,
         allreduce_payload=(None if guard_floats is None
                            else ("f32", E * int(guard_floats))),
-        max_payload_cells=bound,
+        max_payload_cells=_local_block_cells(fields) * E,
         meta=dict(meta or {}, dims=[int(d) for d in gg.dims],
                   periods=[int(p) for p in gg.periods],
                   **({"ensemble": E} if E > 1 else {}),
-                  **({"comm_every": str(cad)} if cad.deep else {}),
-                  **({"wire_stage": str(stg)} if stg is not None else {})))
+                  **({"comm_every": str(cad)} if cad.deep else {})))
 
 
 def model_contract(model, fields, *, dims=None, coalesce=None,
                    wire_dtype=None, impl: str = "xla",
                    guard_floats: int | None = None,
                    ensemble: int | None = None,
-                   comm_every=None, wire_stage=None) -> CollectiveContract:
+                   comm_every=None) -> CollectiveContract:
     """The step contract of a model family: exchange rounds from
     `telemetry.STEP_WORKLOADS[model]`, priced over the model's state
     ``fields`` (canonical state order — PHYSICAL per-member shapes when
@@ -490,7 +403,7 @@ def model_contract(model, fields, *, dims=None, coalesce=None,
     return exchange_contract(
         *fields, rounds=work.groups_for(impl, deep=cad.deep), dims=dims,
         coalesce=coalesce, wire_dtype=wire_dtype, guard_floats=guard_floats,
-        ensemble=ensemble, comm_every=comm_every, wire_stage=wire_stage,
+        ensemble=ensemble, comm_every=comm_every,
         meta={"model": str(model), "impl": str(impl)})
 
 
@@ -653,7 +566,7 @@ def perfmodel_crosscheck(model, fields, ir: ProgramIR, *, profile=None,
                          dims=None, coalesce=None, wire_dtype=None,
                          impl: str = "xla",
                          ensemble: int | None = None,
-                         comm_every=None, wire_stage=None) -> dict:
+                         comm_every=None) -> dict:
     """Prove `telemetry.predict_step`'s collective pricing against the
     compiled program: per mesh axis, the oracle's priced ppermute PAIRS
     and all-links wire bytes must equal what the parser measured in the
@@ -666,14 +579,7 @@ def perfmodel_crosscheck(model, fields, ir: ProgramIR, *, profile=None,
     the parsed program is the compiled SUPER-STEP (one cadence cycle):
     the oracle's per-exchange pairs scale by each axis's
     ``cycle / k_d`` events per cycle — proving the per-axis amortization
-    (latency term ÷ k_axis) against exactly what the compiler emitted.
-    With ``wire_stage`` the oracle prices the hierarchical staged
-    program: a staged axis's gather/scatter hops ride the GATHER axis's
-    routes in the compiled program, so the per-axis comparison runs
-    against the route-attributed plan merge and the oracle-vs-plan
-    self-consistency check moves to the TOTAL pair count (the oracle
-    books every staged op under the staged axis; the attribution books
-    it where the parser will see it — same total, different split)."""
+    (latency term ÷ k_axis) against exactly what the compiler emitted."""
     from ..ops.wire import resolve_comm_every
     from ..parallel.topology import check_initialized, global_grid
     from ..telemetry.perfmodel import predict_step
@@ -683,19 +589,16 @@ def perfmodel_crosscheck(model, fields, ir: ProgramIR, *, profile=None,
     cad = resolve_comm_every(comm_every if comm_every is not None else 1)
     pred = predict_step(model, fields, profile=profile, dims=dims,
                         coalesce=coalesce, wire_dtype=wire_dtype, impl=impl,
-                        ensemble=ensemble, comm_every=cad,
-                        wire_stage=wire_stage)
+                        ensemble=ensemble, comm_every=cad)
     plan = _merged_plan(fields,
                         _exchange_rounds(model, len(fields), impl,
                                          deep=cad.deep),
                         dims=dims, coalesce=coalesce, wire_dtype=wire_dtype,
-                        ensemble=ensemble, comm_every=cad,
-                        wire_stage=wire_stage)
+                        ensemble=ensemble, comm_every=cad)
     parsed = measure_axes(ir, axis_routes(gg))
     from ..parallel.topology import AXIS_NAMES
 
     axis_dim = {a: i for i, a in enumerate(AXIS_NAMES)}
-    staged_axes = {a for a, c in pred["comm"].items() if "staged" in c}
     findings: list = []
     axes: dict = {}
 
@@ -709,30 +612,13 @@ def perfmodel_crosscheck(model, fields, ir: ProgramIR, *, profile=None,
     # all-links bytes from this module's round merge — the two price
     # the SAME rounds from the SAME plan, so a disagreement between
     # them means one merge loop was edited without the other: flag it
-    # rather than crosscheck against a self-inconsistent model. With a
-    # staged axis the split across axes legitimately differs (route
-    # attribution vs link class), so the check runs on the TOTALS.
-    oracle_total = sum(_events(a) * c["ppermute_pairs"]
-                       for a, c in pred["comm"].items())
-    plan_total = sum(r["permutes"] for r in plan.values()) / 2.0
-    if staged_axes:
-        if plan_total != oracle_total:
-            findings.append(AuditFinding(
-                "model-inconsistent", SEV_ERROR,
-                f"predict_step prices {oracle_total} ppermute pairs "
-                f"total but the plan merge counts {plan_total} — the "
-                "model's two round-merge paths have diverged "
-                "(fix telemetry.perfmodel / analysis.contracts before "
-                "trusting the crosscheck).",
-                details={"predict_step_pairs": oracle_total,
-                         "plan_pairs": plan_total,
-                         "staged_axes": sorted(staged_axes)}))
+    # rather than crosscheck against a self-inconsistent model.
     for axis in sorted(set(plan) | set(k for k in parsed if k is not None)):
         modeled_pairs = _events(axis) * pred["comm"].get(axis, {}).get(
             "ppermute_pairs", 0.0)
         modeled_bytes = plan.get(axis, {}).get("wire_bytes", 0)
         plan_pairs = plan.get(axis, {}).get("permutes", 0) / 2.0
-        if not staged_axes and plan_pairs != modeled_pairs:
+        if plan_pairs != modeled_pairs:
             findings.append(AuditFinding(
                 "model-inconsistent", SEV_ERROR,
                 f"axis {axis!r}: predict_step prices {modeled_pairs} "
@@ -742,10 +628,6 @@ def perfmodel_crosscheck(model, fields, ir: ProgramIR, *, profile=None,
                 "trusting the crosscheck).",
                 details={"axis": axis, "predict_step_pairs": modeled_pairs,
                          "plan_pairs": plan_pairs}))
-        if staged_axes:
-            # compare the parser against the route-attributed merge —
-            # where the compiled program actually carries each stage
-            modeled_pairs = plan_pairs
         got = parsed.get(axis, {"permutes": 0, "wire_bytes": 0})
         got_pairs = got["permutes"] / 2.0
         axes[axis] = {"modeled_pairs": modeled_pairs,
@@ -772,7 +654,6 @@ def perfmodel_crosscheck(model, fields, ir: ProgramIR, *, profile=None,
             "model": str(model), "impl": str(impl),
             "ensemble": int(pred.get("ensemble", 1)),
             "comm_every": str(cad),
-            "wire_stage": pred.get("wire_stage"),
             "profile_source": pred["profile_source"]}
 
 

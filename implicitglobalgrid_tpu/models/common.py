@@ -302,26 +302,20 @@ def make_state_runner(step_local, state_ndims, *, nt_chunk: int, key=None,
     unroll = max(1, min(int(unroll), int(nt_chunk)))
     t_build0 = time.monotonic()
     if key is not None:
-        # kernel_flags are read at TRACE time inside the kernel builders;
-        # keying on them keeps the documented IGG_MP_HANDOFF /
-        # IGG_PLANE_RELAY A/B flips honest within one grid epoch (no
-        # stale cached runner). Same rule for the halo exchange knobs
-        # (IGG_HALO_COALESCE / IGG_HALO_WIRE_DTYPE / IGG_HALO_WIRE_STAGE),
-        # resolved at trace time inside `local_update_halo` calls in the
-        # step body.
+        # the halo exchange knobs (IGG_HALO_COALESCE / IGG_HALO_WIRE_DTYPE)
+        # are resolved at TRACE time inside `local_update_halo` calls in
+        # the step body; keying on them keeps a flip within one grid epoch
+        # honest (no stale cached runner).
         from ..ops.halo import resolve_halo_coalesce
-        from ..ops.pallas_stencil import kernel_flags
         from ..ops.precision import resolve_wire_dtype
-        from ..ops.wire import resolve_wire_stage
 
         hook_id = None if post_chunk is None else (
             getattr(post_chunk, "__module__", None),
             getattr(post_chunk, "__qualname__", repr(post_chunk)))
         full_key = (gg.epoch, key, tuple(state_ndims), int(nt_chunk),
-                    bool(check_vma), int(unroll), kernel_flags(),
+                    bool(check_vma), int(unroll),
                     resolve_halo_coalesce(None),
-                    str(resolve_wire_dtype(None)),
-                    str(resolve_wire_stage(None)), hook_id, ensemble)
+                    str(resolve_wire_dtype(None)), hook_id, ensemble)
         fn = _runner_cache.get(full_key)
         if fn is not None:
             # telemetry: compiled-chunk reuse vs recompile is THE

@@ -85,9 +85,7 @@ from .fields import (
     Field, check_fields, extract, field_partition_spec, wrap_field,
 )
 from .precision import resolve_wire_dtype, wire_format_for
-from .wire import (
-    StagedWireSchema, resolve_wire_stage, schema_for_fields, slab_schema,
-)
+from .wire import schema_for_fields, slab_schema
 
 __all__ = ["update_halo", "local_update_halo", "free_update_halo_caches",
            "halo_may_use_pallas", "resolve_halo_coalesce", "halo_comm_plan",
@@ -441,31 +439,11 @@ def _perm_pairs(D, periodic, disp):
     """The (forward, backward) ppermute pairs of an exchanging axis —
     wrap-around when periodic, truncated chains (PROC_NULL edges) when not.
     Delegates to `parallel.topology.axis_perm_pairs`: ONE pair generator
-    shared by the per-field path, the coalesced path, the staged wire's
-    intra/cross partition, and the contracts, so the wire pattern can
-    never diverge between layers."""
+    shared by the per-field path, the coalesced path, and the contracts,
+    so the wire pattern can never diverge between layers."""
     from ..parallel.topology import axis_perm_pairs
 
     return axis_perm_pairs(D, periodic, disp)
-
-
-def _staged_layouts(gg, stage) -> dict:
-    """``{dim: StagedWireLayout}`` for every dim the resolved
-    `WireStagePolicy` stages AND whose granule geometry supports it
-    (`parallel.topology.staged_wire_layout`). The one routing decision —
-    the live exchange, the static plan, the perf oracle, and the
-    contracts all consult this, so a degenerate axis degrades to the
-    flat pair identically everywhere."""
-    if stage is None:
-        return {}
-    from ..parallel.topology import staged_wire_layout
-
-    out = {}
-    for d in stage.staged_dims:
-        lay = staged_wire_layout(gg, d)
-        if lay is not None:
-            out[d] = lay
-    return out
 
 
 def _check_slab_fit(s, dim, ol_d, hw):
@@ -477,7 +455,7 @@ def _check_slab_fit(s, dim, ol_d, hw):
 
 
 def _coalesce_groups(gg, arrays, hws, handled, dims_order, coalesce=True,
-                     wire=None, staged_dims=frozenset()):
+                     wire=None):
     """Packing plan for the coalesced exchange: ``{dim: [group, ...]}``
     where each group is a tuple of field indices of ONE dtype that all
     exchange along ppermute axis ``dim``. Without wire quantization a
@@ -487,12 +465,7 @@ def _coalesce_groups(gg, arrays, hws, handled, dims_order, coalesce=True,
     ``dim`` always rides the packed path — its payload carries the
     appended per-slab scales, a layout only the flat buffer has — even as
     a singleton, and with ``coalesce=False`` each quantized field packs
-    its own buffer (per-field collective count preserved). A dim in
-    ``staged_dims`` (the topology-staged wire) likewise forces the packed
-    path for EVERY exchanging field — the staged pipeline routes one
-    packed buffer per group through gather/DCN/scatter, so even
-    singletons pack (packing a lone slab is pure layout: bit-identity to
-    the per-field wire is preserved)."""
+    its own buffer (per-field collective count preserved)."""
     out = {}
     for dim in dims_order:
         D, periodic, disp = _dim_meta(gg, dim)
@@ -508,10 +481,9 @@ def _coalesce_groups(gg, arrays, hws, handled, dims_order, coalesce=True,
         for dt, idxs in by_dt.items():
             fmt = wire_format_for(dt, wire, dim)
             quant = fmt is not None and fmt.is_quant
-            packed = quant or dim in staged_dims
-            if packed and not coalesce:
+            if quant and not coalesce:
                 groups.extend((i,) for i in idxs)
-            elif packed or (coalesce and len(idxs) >= 2):
+            elif quant or (coalesce and len(idxs) >= 2):
                 groups.append(tuple(idxs))
         if groups:
             out[dim] = groups
@@ -629,134 +601,7 @@ def _exchange_dim_coalesced(gg, arrays, idxs, hws, dim, wire=None):
                                                         axis=dim)
 
 
-def _exchange_dim_staged(gg, arrays, idxs, hws, dim, wire, layout):
-    """Exchange the halos of fields ``idxs`` (one dtype) along staged dim
-    ``dim`` through the hierarchical three-stage pipeline
-    (`parallel.topology.StagedWireLayout`):
-
-    1. **gather** — ``fold - 1`` pipelined ppermute shifts along the
-       gather (ICI) axis walk every sending plane's packed buffer toward
-       the per-granule leader (gather coord 0), which records one slot
-       per hop;
-    2. **dcn** — the leaders stack their ``fold`` slots and ONE ppermute
-       per direction ships the stripe leader -> leader across the granule
-       boundary (per-DCN-link message count drops by the ICI fold);
-    3. **scatter** — ``fold - 1`` reverse shifts fan the stripe back out:
-       the far leader injects pieces farthest-first, every non-leader's
-       own slab arrives in the final round.
-
-    Same-granule pairs keep the flat single-axis ppermute (``intra``) and
-    a mesh-coordinate select stitches the two results before the shared
-    PROC_NULL masking and delivery of the flat path. The payload is the
-    SAME `WireSchema.pack` buffer the flat coalesced exchange ships —
-    never transformed, only routed — so delivered halos are BIT-IDENTICAL
-    to the flat wire, and a quantized payload's per-slab scales ride
-    in-band through all three stages. Mutates ``arrays``."""
-    import functools
-
-    import jax.numpy as jnp
-    from jax import lax
-
-    D, periodic, disp = _dim_meta(gg, dim)
-    axis_name = AXIS_NAMES[dim]
-    gather_name = AXIS_NAMES[layout.gather_dim]
-    F = int(layout.fold)
-
-    metas = []  # (i, hw, s, slab_shape)
-    sends_r, sends_l, curs_l, curs_r = [], [], [], []
-    for i in idxs:
-        a = arrays[i]
-        hw = int(hws[i][dim])
-        s = a.shape[dim]
-        ol_d = int(gg.overlaps[dim] + (s - gg.nxyz[dim]))
-        _check_slab_fit(s, dim, ol_d, hw)
-        send_r = lax.slice_in_dim(a, s - ol_d, s - ol_d + hw, axis=dim)
-        send_l = lax.slice_in_dim(a, ol_d - hw, ol_d, axis=dim)
-        metas.append((i, hw, s, send_r.shape))
-        sends_r.append(send_r)
-        sends_l.append(send_l)
-        if not periodic:
-            curs_l.append(lax.slice_in_dim(a, 0, hw, axis=dim))
-            curs_r.append(lax.slice_in_dim(a, s - hw, s, axis=dim))
-
-    state_dt = arrays[idxs[0]].dtype
-    fmt = wire_format_for(state_dt, wire, dim)
-    schema = slab_schema(dim, [m[3] for m in metas], state_dt, fmt)
-    # staged payloads take the XLA pack (no fused pack kernel: the routed
-    # buffer is consumed by collectives, not a single unpack launch)
-    buf_r = schema.pack(sends_r)
-    buf_l = schema.pack(sends_l)
-
-    g_idx = lax.axis_index(gather_name)
-    d_idx = lax.axis_index(axis_name)
-    is_leader = g_idx == 0
-
-    def staged_route(buf, dr):
-        # gather: F-1 pipelined shifts toward the leader; the leader's
-        # slot r holds the buffer that ORIGINATED at gather coord r
-        fwd = buf
-        slots = [buf]
-        for _ in range(F - 1):
-            fwd = lax.ppermute(fwd, AXIS_NAMES, dr.gather_pairs)
-            slots.append(fwd)
-        stripe = jnp.stack(slots)
-        # ONE striped DCN transfer per direction (leader -> leader)
-        got = lax.ppermute(stripe, AXIS_NAMES, dr.dcn_pairs)
-        # scatter: leader injects farthest-first; the piece injected in
-        # round r reaches gather coord k = F - r in the final round, so
-        # every non-leader's own slab is its LAST received value
-        cur = jnp.zeros_like(buf)
-        for r in range(1, F):
-            send = jnp.where(is_leader, got[F - r], cur)
-            cur = lax.ppermute(send, AXIS_NAMES, dr.scatter_pairs)
-        return jnp.where(is_leader, got[0], cur)
-
-    def one_direction(buf, dr):
-        if not dr.cross_pairs:  # nothing crosses a granule: stay flat
-            return lax.ppermute(buf, axis_name, dr.axis_pairs)
-        staged = staged_route(buf, dr)
-        flat = (lax.ppermute(buf, axis_name, dr.intra_pairs)
-                if dr.intra_pairs else jnp.zeros_like(buf))
-        tmask = functools.reduce(
-            jnp.logical_or, [d_idx == t for t in dr.cross_targets])
-        return jnp.where(tmask, staged, flat)
-
-    dir_p, dir_m = layout.directions
-    recv_l = schema.unpack(one_direction(buf_r, dir_p))
-    recv_r = schema.unpack(one_direction(buf_l, dir_m))
-    if not periodic:  # PROC_NULL edges keep current halos EXACT
-        recv_l = [jnp.where(d_idx >= disp, rl, cur)
-                  for rl, cur in zip(recv_l, curs_l)]
-        recv_r = [jnp.where(d_idx < D - disp, rr, cur)
-                  for rr, cur in zip(recv_r, curs_r)]
-    slab_pairs = list(zip(recv_l, recv_r))
-
-    use_multi, interp = _coalesced_pallas_mode(
-        gg, dim, [arrays[i].shape for i in idxs], [m[1] for m in metas])
-    if use_multi:
-        from .pallas_halo import halo_write_multi_pallas
-
-        outs = halo_write_multi_pallas(
-            [arrays[i] for i in idxs], slab_pairs,
-            dim=dim, hw=metas[0][1], interpret=interp)
-        for i, o in zip(idxs, outs):
-            arrays[i] = o
-        return
-    for (i, hw, s, _), (rl, rr) in zip(metas, slab_pairs):
-        pw, interp = _pallas_write_mode(gg, dim, arrays[i].shape, hw)
-        if pw:
-            from .pallas_halo import halo_write_inplace
-
-            arrays[i] = halo_write_inplace(arrays[i], rl, rr, dim=dim, hw=hw,
-                                           interpret=interp)
-        else:
-            a = lax.dynamic_update_slice_in_dim(arrays[i], rl, 0, axis=dim)
-            arrays[i] = lax.dynamic_update_slice_in_dim(a, rr, s - hw,
-                                                        axis=dim)
-
-
-def _exchange_arrays(gg, arrays, hws, dims_order, coalesce=None, wire=None,
-                     stage=None):
+def _exchange_arrays(gg, arrays, hws, dims_order, coalesce=None, wire=None):
     """Exchange every field's halos (local view; inside shard_map).
     Mutates and returns ``arrays``. Kernel-path selection per field:
     all-self single-pass kernel > coalesced packed exchange (multi-field
@@ -767,19 +612,12 @@ def _exchange_arrays(gg, arrays, hws, dims_order, coalesce=None, wire=None,
     or None for full-precision wire. Wire mode routes its fields through
     the coalesced/per-dim paths (the combined one-pass tier has its own
     full-precision permutes); quantized formats always ride the packed
-    path (the scales live in the flat buffer — `_coalesce_groups`).
-    ``stage`` is the RESOLVED topology-staging policy
-    (`wire.resolve_wire_stage`) or None for the flat wire everywhere: a
-    staged dim's fields always ride the packed path and its groups go
-    through the hierarchical three-stage exchange
-    (`_exchange_dim_staged`) instead of the flat pair."""
+    path (the scales live in the flat buffer — `_coalesce_groups`)."""
     if coalesce is None:
         coalesce = resolve_halo_coalesce(None)
     handled = _apply_self_exchange(gg, arrays, hws, dims_order)
-    staged = _staged_layouts(gg, stage)
     groups_by_dim = _coalesce_groups(gg, arrays, hws, handled, dims_order,
-                                     coalesce=coalesce, wire=wire,
-                                     staged_dims=frozenset(staged))
+                                     coalesce=coalesce, wire=wire)
     grouped = {i for gs in groups_by_dim.values() for g in gs for i in g}
     def wire_touches(a, hw):
         # whether the policy can actually reach one of THIS field's
@@ -792,21 +630,13 @@ def _exchange_arrays(gg, arrays, hws, dims_order, coalesce=None, wire=None,
             and _dim_exchanges(gg, a.shape, hw, d)
             for d in dims_order)
 
-    def stage_touches(a, hw):
-        # staged dims must take the staged route — the combined one-pass
-        # tier's permutes are flat
-        return any(
-            d in staged and _dim_exchanges(gg, a.shape, hw, d)
-            for d in dims_order)
-
     for i, a in enumerate(arrays):
         # wire-affected fields skip the combined tier (its permutes are
         # full-precision); fields the wire policy can never touch (ints,
         # already-narrow floats, fields whose policy-named dims carry no
         # ppermute for them) keep the faster one-pass kernel — evicting
         # those would pay per-dim exchanges for bit-identical results.
-        if handled[i] or i in grouped or wire_touches(a, hws[i]) \
-                or stage_touches(a, hws[i]):
+        if handled[i] or i in grouped or wire_touches(a, hws[i]):
             continue
         modes = _combined_plan(gg, a.shape, hws[i], dims_order)
         if modes is not None:
@@ -820,11 +650,7 @@ def _exchange_arrays(gg, arrays, hws, dims_order, coalesce=None, wire=None,
         in_group = set()
         for g in groups_by_dim.get(dim, ()):
             in_group.update(g)
-            if dim in staged:
-                _exchange_dim_staged(gg, arrays, list(g), hws, dim, wire,
-                                     staged[dim])
-            else:
-                _exchange_dim_coalesced(gg, arrays, list(g), hws, dim, wire)
+            _exchange_dim_coalesced(gg, arrays, list(g), hws, dim, wire)
         for i, a in enumerate(arrays):
             if handled[i] or i in in_group or dim >= a.ndim:
                 continue
@@ -912,8 +738,7 @@ def _exchange_dim_local(a, *, dim, hw, ol_d, D, periodic, disp, axis_name,
     return write_halos(a, recv_l, recv_r)
 
 
-def local_update_halo(*fields, dims=None, coalesce=None, wire_dtype=None,
-                      wire_stage=None):
+def local_update_halo(*fields, dims=None, coalesce=None, wire_dtype=None):
     """Halo-exchange local blocks — use INSIDE `shard_map` over the grid mesh.
 
     This is the local-view programming model of the reference (user code runs
@@ -929,10 +754,7 @@ def local_update_halo(*fields, dims=None, coalesce=None, wire_dtype=None,
     ``wire_dtype`` ships float payloads across the link narrowed (float
     casts) or per-slab-scale quantized (``int8``/``int4``), optionally per
     mesh axis (``"z:int8,x:f32"``) — default from ``IGG_HALO_WIRE_DTYPE``
-    (OFF); ``wire_stage`` routes a DCN-crossing axis's exchange through
-    the hierarchical ICI-gather -> striped-DCN -> ICI-scatter pipeline
-    (``"z:staged"``) — default from ``IGG_HALO_WIRE_STAGE`` (OFF); see
-    the module docstring.
+    (OFF); see the module docstring.
 
     NOTE: on a default TPU grid this emits Pallas kernels (in-place halo
     writes / single-pass self-exchange), which cannot pass `shard_map`'s
@@ -947,15 +769,13 @@ def local_update_halo(*fields, dims=None, coalesce=None, wire_dtype=None,
     arrays = _exchange_arrays(gg, [f.A for f in fs],
                               [f.halowidths for f in fs], dims_order,
                               coalesce=resolve_halo_coalesce(coalesce),
-                              wire=resolve_wire_dtype(wire_dtype),
-                              stage=resolve_wire_stage(wire_stage))
+                              wire=resolve_wire_dtype(wire_dtype))
     return arrays[0] if len(arrays) == 1 else tuple(arrays)
 
 
-def _build_exchange_fn(gg, sig, dims_order, coalesce, wire, stage=None):
+def _build_exchange_fn(gg, sig, dims_order, coalesce, wire):
     """Compile the jitted shard_map exchange program for a field signature.
-    ``coalesce``, ``wire``, and ``stage`` are pre-resolved
-    (`update_halo`)."""
+    ``coalesce`` and ``wire`` are pre-resolved (`update_halo`)."""
     import jax
 
     from jax import shard_map
@@ -983,8 +803,7 @@ def _build_exchange_fn(gg, sig, dims_order, coalesce, wire, stage=None):
 
     def exchange(*locals_):
         return tuple(_exchange_arrays(gg, list(locals_), hws, dims_order,
-                                      coalesce=coalesce, wire=wire,
-                                      stage=stage))
+                                      coalesce=coalesce, wire=wire))
 
     shmapped = shard_map(
         exchange, mesh=gg.mesh, in_specs=in_specs, out_specs=in_specs,
@@ -1007,7 +826,7 @@ class _SigField:
 
 
 def _plan_from_sig(gg, sig, dims_order, coalesce, wire,
-                   ensemble=None, stage=None) -> dict:
+                   ensemble=None) -> dict:
     """Static comm accounting for one exchange signature: collective
     counts and bytes-on-wire derived purely from shapes/overlaps/wire
     dtype — no tracing, no device work (the TPU analog of the reference's
@@ -1032,17 +851,7 @@ def _plan_from_sig(gg, sig, dims_order, coalesce, wire,
     self-neighbor local copy) carries E members' slabs — bytes x E,
     launches flat in E. The schema's ``members`` field is the single
     byte source, so quantized payloads price E x the per-(member, slab)
-    scale tails exactly as `WireSchema.payload_bytes` ships them.
-
-    ``stage`` is the resolved `WireStagePolicy` (or None): a staged
-    axis's record switches to the hierarchical three-stage accounting
-    (`StagedWireSchema`) — its permute count is the exact collective
-    launch total (``2*(2*fold - 1)`` per cross direction plus the intra
-    pair) and its bytes are the ABSOLUTE full-mesh wire total (the
-    per-line abstraction the flat records use does not divide the
-    gather/scatter pipelines evenly), flagged by a ``staged`` detail
-    dict (fold, gather axis, per-stage op table, DCN pair counts) so
-    downstream mergers skip the per-line scaling for it."""
+    scale tails exactly as `WireSchema.payload_bytes` ships them."""
     E = 1
     if ensemble is not None:
         E = int(ensemble)
@@ -1078,10 +887,9 @@ def _plan_from_sig(gg, sig, dims_order, coalesce, wire,
     # comm_every cadence amortizes each axis's local swaps at that axis's
     # own rate, so the oracle needs the split, not just the total
     local_by_axis: dict = {}
-    staged = _staged_layouts(gg, stage)
     groups_by_dim = _coalesce_groups(
         gg, fields, hws, [False] * len(fields), dims_order,
-        coalesce=coalesce, wire=wire, staged_dims=frozenset(staged))
+        coalesce=coalesce, wire=wire)
     for dim in dims_order:
         D, periodic, disp = _dim_meta(gg, dim)
         if D == 1 and not periodic:
@@ -1099,24 +907,6 @@ def _plan_from_sig(gg, sig, dims_order, coalesce, wire,
             schema = schema_for_fields(
                 dim, [fields[i].shape for i in g],
                 [hws[i][dim] for i in g], f0.dtype, fmt, members=E)
-            if dim in staged:
-                sws = StagedWireSchema(schema=schema, layout=staged[dim])
-                rec = axis_rec(dim)
-                rec["ppermutes"] += sws.ppermute_ops
-                rec["wire_bytes"] += sws.wire_bytes
-                rec["by_dtype"][schema.wire_key] = (
-                    rec["by_dtype"].get(schema.wire_key, 0) + sws.wire_bytes)
-                det = rec.setdefault("staged", {
-                    "fold": int(sws.layout.fold),
-                    "gather_axis": AXIS_NAMES[sws.layout.gather_dim],
-                    "granules": int(sws.layout.granules),
-                    "dcn_pairs": sws.dcn_pair_count,
-                    "flat_dcn_pairs": sws.flat_dcn_pair_count(),
-                    "stages": [],
-                })
-                det["stages"].extend(
-                    dict(s, group=tuple(g)) for s in sws.stage_table())
-                continue
             add_wire(dim, schema.payload_bytes, schema.wire_key, npairs)
         for i, f in enumerate(fields):
             if i in in_group or not _dim_exchanges(gg, f.shape, hws[i], dim):
@@ -1135,8 +925,6 @@ def _plan_from_sig(gg, sig, dims_order, coalesce, wire,
         "fields": len(fields),
         "coalesce": bool(coalesce),
         "wire_dtype": None if wire is None else str(wire),
-        "wire_stage": None if stage is None else str(stage),
-        "staged_axes": tuple(sorted(AXIS_NAMES[d] for d in staged)),
         "ensemble": E,
         "axes": axes,
         "ppermutes": sum(r["ppermutes"] for r in axes.values()),
@@ -1200,7 +988,7 @@ def _stacked_sig(gg, fs) -> tuple:
 
 
 def halo_comm_plan(*fields, dims=None, coalesce=None, wire_dtype=None,
-                   ensemble=None, wire_stage=None) -> dict:
+                   ensemble=None) -> dict:
     """Static bytes-on-wire / collective-count plan for an `update_halo`
     call with these stacked fields — derived from shapes, overlaps, and
     the wire dtype alone; nothing is compiled or dispatched (zero device
@@ -1215,13 +1003,8 @@ def halo_comm_plan(*fields, dims=None, coalesce=None, wire_dtype=None,
     the SAME ppermute pairs (jax's collective batching under vmap;
     ``ppermutes`` is flat in E by construction).
 
-    ``wire_stage`` prices the topology-staged wire (default from
-    ``IGG_HALO_WIRE_STAGE``): a staged axis's record carries the exact
-    hierarchical collective counts/bytes plus a ``staged`` detail dict
-    (see `_plan_from_sig`).
-
-    Returns ``{fields, coalesce, wire_dtype, wire_stage, staged_axes,
-    ensemble, axes: {axis: {ppermutes, wire_bytes, by_dtype[, staged]}},
+    Returns ``{fields, coalesce, wire_dtype, ensemble,
+    axes: {axis: {ppermutes, wire_bytes, by_dtype}},
     ppermutes, wire_bytes, local_copy_bytes, local_copy_by_axis}``.
     `update_halo` charges exactly this plan to the telemetry registry
     (``igg_halo_*`` counters) on every call."""
@@ -1233,12 +1016,10 @@ def halo_comm_plan(*fields, dims=None, coalesce=None, wire_dtype=None,
     return _plan_from_sig(gg, sig, dims_order,
                           resolve_halo_coalesce(coalesce),
                           resolve_wire_dtype(wire_dtype),
-                          ensemble=ensemble,
-                          stage=resolve_wire_stage(wire_stage))
+                          ensemble=ensemble)
 
 
-def update_halo(*fields, dims=None, coalesce=None, wire_dtype=None,
-                wire_stage=None):
+def update_halo(*fields, dims=None, coalesce=None, wire_dtype=None):
     """Update the halo of the given global (stacked) array(s).
 
     Controller-side API of the reference's `update_halo!`
@@ -1259,12 +1040,7 @@ def update_halo(*fields, dims=None, coalesce=None, wire_dtype=None,
     (default from ``IGG_HALO_WIRE_DTYPE``: OFF) ships float payloads across
     the link at reduced precision — float casts or per-slab-scaled
     ``int8``/``int4`` quantization, per mesh axis (``"z:int8,x:f32"``);
-    ``wire_stage`` (default from ``IGG_HALO_WIRE_STAGE``: OFF) stages a
-    DCN-crossing axis's exchange hierarchically — per-granule ICI
-    leaders gather the packed slabs, ONE striped transfer per
-    granule-pair crosses DCN, the far leader scatters back over ICI
-    (``"z:staged"``; bit-identical halos, per-DCN-link message count
-    divided by the ICI fold); see the module docstring.
+    see the module docstring.
 
     Example (doctest):
 
@@ -1292,20 +1068,17 @@ def update_halo(*fields, dims=None, coalesce=None, wire_dtype=None,
     sig = _stacked_sig(gg, fs)
     coalesce_r = resolve_halo_coalesce(coalesce)
     wire_r = resolve_wire_dtype(wire_dtype)
-    stage_r = resolve_wire_stage(wire_stage)
     key = (grid_epoch(), sig, dims_order, _FORCE_PALLAS_WRITE_INTERPRET,
-           coalesce_r, str(wire_r), str(stage_r))
+           coalesce_r, str(wire_r))
     fn = _exchange_cache.get(key)
     if fn is None:
-        fn = _build_exchange_fn(gg, sig, dims_order, coalesce_r, wire_r,
-                                stage_r)
+        fn = _build_exchange_fn(gg, sig, dims_order, coalesce_r, wire_r)
         _exchange_cache[key] = fn
     # Static comm accounting: charge the signature's wire plan per call
     # (computed once per signature, pure host arithmetic — no syncs).
     plan = _plan_cache.get(key)
     if plan is None:
-        plan = _plan_from_sig(gg, sig, dims_order, coalesce_r, wire_r,
-                              stage=stage_r)
+        plan = _plan_from_sig(gg, sig, dims_order, coalesce_r, wire_r)
         _plan_cache[key] = plan
     from ..telemetry import account_halo_exchange
 

@@ -772,38 +772,17 @@ def _window_pipeline_handoff(ref, scratch, sems, *, nx, B):
     return scratch.at[cur], i * B - wstart(i)
 
 
-def window_handoff_enabled() -> bool:
-    """`IGG_MP_HANDOFF=0` forces the plain re-reading window pipeline in
-    every kernel family (A/B measurement)."""
-    import os
-
-    return os.environ.get("IGG_MP_HANDOFF", "1") != "0"
-
-
-def plane_relay_enabled() -> bool:
-    """`IGG_PLANE_RELAY=0` restores the HBM ``[i-1]`` input streams in the
-    plane-per-program kernels (A/B measurement / Mosaic escape hatch)."""
-    import os
-
-    return os.environ.get("IGG_PLANE_RELAY", "1") != "0"
-
-
-def kernel_flags() -> tuple:
-    """Trace-time kernel-variant flags — part of every runner cache key so
-    flipping either env var retraces instead of replaying stale kernels."""
-    return (window_handoff_enabled(), plane_relay_enabled())
-
-
 def handoff_ok(nx, P) -> bool:
     """The shared window-handoff gate for every kernel family: >= 3
-    windows (the 2-window case has a 4-plane overlap) and the env flag."""
-    return P is not None and nx // P >= 3 and window_handoff_enabled()
+    windows (the 2-window case has a 4-plane overlap and keeps the plain
+    re-reading `_window_pipeline`)."""
+    return P is not None and nx // P >= 3
 
 
 def mp_handoff(T, interpret=False) -> bool:
     """Whether the multi-plane kernel uses the VMEM window handoff (1.0x T
-    reads) for this shape: needs >= 3 windows; `IGG_MP_HANDOFF=0` forces
-    the plain (1+2/P)x pipeline for A/B measurement."""
+    reads) for this shape: needs >= 3 windows, else the plain (1+2/P)x
+    pipeline."""
     return handoff_ok(int(T.shape[0]), mp_planes(T, interpret=interpret))
 
 
@@ -1037,8 +1016,7 @@ def strip_rows_2d(T, interpret=False):
     return None
 
 
-def _strip2d_kernel(*refs, nx, R, H, modes, lam, dt, dx, dy,
-                    handoff=False):
+def _strip2d_kernel(*refs, nx, R, H, modes, lam, dt, dx, dy):
     """Compute R output rows from a manually DMA'd VMEM strip of T, then
     deliver the received halo slabs: x whole rows first, then y lanes — the
     exchange order for 2-D blocks (dims 0 then 1 of the z, x, y default;
@@ -1051,9 +1029,11 @@ def _strip2d_kernel(*refs, nx, R, H, modes, lam, dt, dx, dy,
     sized (Mosaic rejects 1-row slices and dynamic-offset multi-row vector
     loads alike); the rows just above/below the strip are the last/first
     rows of those tiles. Tile fetches clamp at the global edges, where the
-    garbage row only reaches globally-masked boundary rows. All three are
-    double-buffered across the sequential grid like `_window_pipeline`;
-    tm/tp are edge-patched shifts of the body."""
+    garbage row only reaches globally-masked boundary rows. Body and below
+    tiles are double-buffered across the sequential grid like
+    `_window_pipeline`; every above tile after the first is the previous
+    body's last H rows, handed across in VMEM. tm/tp are edge-patched
+    shifts of the body."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
@@ -1100,25 +1080,20 @@ def _strip2d_kernel(*refs, nx, R, H, modes, lam, dt, dx, dy,
     def _():
         body_dma((i + 1) % 2, i + 1).start()
         below_dma((i + 1) % 2, i + 1).start()
-        if not handoff:
-            above_dma((i + 1) % 2, i + 1).start()
 
     slot = i % 2
     body_dma(slot, i).wait()
     below_dma(slot, i).wait()
-    if handoff:
-        # the above tile for g >= 1 is the tail of the PREVIOUS body —
-        # handed across in VMEM by the previous program (below); only
-        # program 0 fetched its (edge-clamped) above tile from HBM
-        @pl.when(i == 0)
-        def _():
-            above_dma(0, 0).wait()
+    # the above tile for g >= 1 is the tail of the PREVIOUS body — handed
+    # across in VMEM by the previous program; only program 0 fetched its
+    # (edge-clamped) above tile from HBM
+    @pl.when(i == 0)
+    def _():
+        above_dma(0, 0).wait()
 
-        @pl.when(i + 1 < nprog)
-        def _():
-            above_scr[(i + 1) % 2] = body_scr[slot][R - H:, :]
-    else:
-        above_dma(slot, i).wait()
+    @pl.when(i + 1 < nprog)
+    def _():
+        above_scr[(i + 1) % 2] = body_scr[slot][R - H:, :]
 
     g0 = i * R
     tc = body_scr[slot]                                        # (R, ny)
@@ -1193,11 +1168,9 @@ def diffusion2d_step_exchange_pallas(T, Cp, gg, modes, *, lam, dt, dx, dy,
         out_shape = jax.ShapeDtypeStruct(T.shape, T.dtype)
 
     H = _sublane_tile(T.dtype)
+    # the above-tile handoff needs no gate: the overlap is uniform and
+    # `strip_rows_2d` guarantees >= 2 strips
     kernel = partial(_strip2d_kernel, nx=nx, R=R, H=H,
-                     # above-tile handoff: the overlap is uniform and
-                     # `strip_rows_2d` guarantees >= 2 strips, so only
-                     # the env flag gates it
-                     handoff=window_handoff_enabled(),
                      modes=tuple(bool(m) for m in modes), **consts)
     kwargs = _sequential_grid_params(interpret)
     return pl.pallas_call(

@@ -162,13 +162,15 @@ from .pallas_common import recv_kinds as _stokes_recv_kinds
 
 
 def _stokes_kernel(*refs, nx, modes, mu, dt_v, dt_p, damp, dx, dy, dz,
-                   self_ols=None, self_z=None, relay=True):
+                   self_ols=None, self_z=None):
     """One x-plane of the fused PT iteration. Arithmetic mirrors
     `models.stokes._stokes_terms` term-for-term (same accumulation order)
     restricted to this plane; then the interior-masked dV/V updates and the
     halo deliveries (z, x, y per field; Vx's x planes post-kernel). With
     ``self_z`` (field -> z overlap) the z halo is folded from the plane's
-    own lanes (`fold_z_lanes`) and no z recv operand is taken.
+    own lanes (`fold_z_lanes`) and no z recv operand is taken. The
+    ``[i-1]`` planes of P, Vx, Vy and Vz arrive by VMEM relay
+    (`pallas_common.plane_relay`), not as HBM streams.
 
     Every intermediate stays at FULL plane size, positioned on a canonical
     grid and shifted with the edge-cloning operators of `pallas_common`
@@ -183,21 +185,14 @@ def _stokes_kernel(*refs, nx, modes, mu, dt_v, dt_p, damp, dx, dy, dz,
     from jax.experimental import pallas as pl
 
     from .pallas_common import deliver_recvs as _deliver
-    from .pallas_common import fold_z_lanes
+    from .pallas_common import fold_z_lanes, plane_relay
     from .pallas_common import shift_down, shift_left, shift_right, shift_up
 
     it = iter(refs)
-    if relay:
-        # the [i-1] planes arrive by VMEM relay (below), not HBM streams
-        p_c = next(it)[0]
-        vxc, vxp = (next(it)[0] for _ in range(2))
-        vyc, vyp = (next(it)[0] for _ in range(2))
-        vzc, vzp = (next(it)[0] for _ in range(2))
-    else:
-        p_m, p_c = (next(it)[0] for _ in range(2))
-        vxm, vxc, vxp = (next(it)[0] for _ in range(3))
-        vym, vyc, vyp = (next(it)[0] for _ in range(3))
-        vzm, vzc, vzp = (next(it)[0] for _ in range(3))
+    p_c = next(it)[0]
+    vxc, vxp = (next(it)[0] for _ in range(2))
+    vyc, vyp = (next(it)[0] for _ in range(2))
+    vzc, vzp = (next(it)[0] for _ in range(2))
     dvxc = next(it)[0]
     dvyc = next(it)[0]
     dvzc = next(it)[0]
@@ -212,17 +207,12 @@ def _stokes_kernel(*refs, nx, modes, mu, dt_v, dt_p, damp, dx, dy, dz,
     rVz = take_recvs(it, modes, "Vz", kinds["Vz"])
 
     i = pl.program_id(0)
-    if relay:
-        from .pallas_common import plane_relay
-
-        oP, oVx, oVy, oVz, odVx, odVy, odVz = refs[-11:-4]
-        relP, relVx, relVy, relVz = refs[-4:]
-        p_m = plane_relay(relP, i, p_c)
-        vxm = plane_relay(relVx, i, vxc)
-        vym = plane_relay(relVy, i, vyc)
-        vzm = plane_relay(relVz, i, vzc)
-    else:
-        oP, oVx, oVy, oVz, odVx, odVy, odVz = refs[-7:]
+    oP, oVx, oVy, oVz, odVx, odVy, odVz = refs[-11:-4]
+    relP, relVx, relVy, relVz = refs[-4:]
+    p_m = plane_relay(relP, i, p_c)
+    vxm = plane_relay(relVx, i, vxc)
+    vym = plane_relay(relVy, i, vyc)
+    vzm = plane_relay(relVz, i, vzc)
     ny, nz = p_c.shape
 
     def d_y(a):  # cell-centred face difference (full size: (ny+1,.) -> (ny,.))
@@ -386,24 +376,18 @@ def stokes_step_exchange_pallas(state, gg, modes, p, *, interpret=False):
     def spec(shape, index_map):
         return pl.BlockSpec(shape, index_map)
 
-    from .pallas_stencil import plane_relay_enabled
-
-    relay = plane_relay_enabled()
     cP = (1, ny, nz)
     cY = (1, ny + 1, nz)
     cZ = (1, ny, nz + 1)
-    operands = [P, P, Vx, Vx, Vx, Vy, Vy, Vy, Vz, Vz, Vz,
-                dVx, dVy, dVz, rhog]
+    # the [i-1] planes come from the in-kernel VMEM relay: 11 HBM input
+    # streams, not 15
+    operands = [P, Vx, Vx, Vy, Vy, Vz, Vz, dVx, dVy, dVz, rhog]
     in_specs = [
-        spec(cP, lambda i: (jnp.maximum(i - 1, 0), 0, 0)),    # P[i-1]
         spec(cP, lambda i: (i, 0, 0)),                        # P[i]
-        spec(cP, lambda i: (jnp.maximum(i - 1, 0), 0, 0)),    # Vx[i-1]
         spec(cP, lambda i: (i, 0, 0)),                        # Vx[i]
         spec(cP, lambda i: (i + 1, 0, 0)),                    # Vx[i+1]
-        spec(cY, lambda i: (jnp.maximum(i - 1, 0), 0, 0)),    # Vy[i-1]
         spec(cY, lambda i: (i, 0, 0)),                        # Vy[i]
         spec(cY, lambda i: (jnp.minimum(i + 1, nx - 1), 0, 0)),
-        spec(cZ, lambda i: (jnp.maximum(i - 1, 0), 0, 0)),    # Vz[i-1]
         spec(cZ, lambda i: (i, 0, 0)),                        # Vz[i]
         spec(cZ, lambda i: (jnp.minimum(i + 1, nx - 1), 0, 0)),
         spec(cP, lambda i: (i, 0, 0)),                        # dVx[i]
@@ -411,12 +395,6 @@ def stokes_step_exchange_pallas(state, gg, modes, p, *, interpret=False):
         spec(cZ, lambda i: (i, 0, 0)),                        # dVz[i]
         spec(cP, lambda i: (i, 0, 0)),                        # rhog[i]
     ]
-    if relay:
-        # [i-1] streams (operand indices: P 0, Vx 2, Vy 5, Vz 8) replaced
-        # by the in-kernel VMEM relay: 11 HBM input streams instead of 15
-        for idx in (8, 5, 2, 0):
-            del operands[idx]
-            del in_specs[idx]
 
     from .pallas_common import add_recv_operands, out_shape_with_vma
 
@@ -443,7 +421,7 @@ def stokes_step_exchange_pallas(state, gg, modes, p, *, interpret=False):
         return out_shape_with_vma(a, operands)
 
     kernel = partial(
-        _stokes_kernel, nx=nx, relay=relay,
+        _stokes_kernel, nx=nx,
         modes={k: tuple(bool(b) for b in v) for k, v in modes.items()},
         mu=dtp(p.mu), dt_v=dtp(p.dt_v), dt_p=dtp(p.dt_p), damp=dtp(p.damp),
         dx=dtp(p.dx), dy=dtp(p.dy), dz=dtp(p.dz), self_ols=self_ols,
@@ -451,17 +429,15 @@ def stokes_step_exchange_pallas(state, gg, modes, p, *, interpret=False):
 
     from jax.experimental.pallas import tpu as pltpu
 
-    extra = {}
-    if relay:
-        extra["scratch_shapes"] = [pltpu.VMEM((2, ny, nz), P.dtype),
-                                   pltpu.VMEM((2, ny, nz), Vx.dtype),
-                                   pltpu.VMEM((2, ny + 1, nz), Vy.dtype),
-                                   pltpu.VMEM((2, ny, nz + 1), Vz.dtype)]
+    extra = {"scratch_shapes": [pltpu.VMEM((2, ny, nz), P.dtype),
+                                pltpu.VMEM((2, ny, nz), Vx.dtype),
+                                pltpu.VMEM((2, ny + 1, nz), Vy.dtype),
+                                pltpu.VMEM((2, ny, nz + 1), Vz.dtype)]}
     recv_yz = not all_self and any(m[1] or m[2] for m in modes.values())
-    if not interpret and (relay or recv_yz):
+    if not interpret:
         extra["compiler_params"] = pltpu.CompilerParams(
             # the relay needs the grid in order
-            dimension_semantics=("arbitrary",) if relay else None,
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES if recv_yz else None)
 
     with _scope("pt"):

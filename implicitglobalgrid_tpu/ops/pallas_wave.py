@@ -225,19 +225,16 @@ from .pallas_common import recv_kinds as _wave_recv_kinds
 
 
 def _wave_kernel(*refs, nx, modes, cx, cy, cz, dtK, dx, dy, dz,
-                 self_ols=None, relay=True):
+                 self_ols=None):
     """Plane-per-program form of the fused step (`_wave_plane_body`).
-    With ``relay``, P[i-1] arrives by VMEM relay instead of a third HBM
-    pressure stream."""
+    P[i-1] arrives by VMEM relay instead of a third HBM pressure
+    stream."""
     from jax.experimental import pallas as pl
 
-    from .pallas_common import take_recvs
+    from .pallas_common import plane_relay, take_recvs
 
     it = iter(refs)
-    if relay:
-        p_c, p_p = (next(it)[0] for _ in range(2))
-    else:
-        p_m, p_c, p_p = (next(it)[0] for _ in range(3))
+    p_c, p_p = (next(it)[0] for _ in range(2))
     vx_c, vx_p = (next(it)[0] for _ in range(2))
     vy_c = next(it)[0]
     vz_c = next(it)[0]
@@ -248,13 +245,8 @@ def _wave_kernel(*refs, nx, modes, cx, cy, cz, dtK, dx, dy, dz,
     rVz = take_recvs(it, modes, "Vz", kinds["Vz"])
 
     i = pl.program_id(0)
-    if relay:
-        from .pallas_common import plane_relay
-
-        oP, oVx, oVy, oVz = refs[-5:-1]
-        p_m = plane_relay(refs[-1], i, p_c)
-    else:
-        oP, oVx, oVy, oVz = refs[-4:]
+    oP, oVx, oVy, oVz = refs[-5:-1]
+    p_m = plane_relay(refs[-1], i, p_c)
     p_new, vx, vy, vz = _wave_plane_body(
         i, nx, p_m, p_c, p_p, vx_c, vx_p, vy_c, vz_c, rP, rVx, rVy, rVz,
         modes=modes, cx=cx, cy=cy, cz=cz, dtK=dtK, dx=dx, dy=dy, dz=dz,
@@ -445,13 +437,9 @@ def acoustic_step_exchange_pallas(state, gg, modes, *, rho, K, dt,
             spec((B, ny, nz + 1), lambda i: (i, 0, 0)),
         ]
     else:
-        from .pallas_stencil import plane_relay_enabled
-
-        relay = plane_relay_enabled()
-        operands = ([P, P, Vx, Vx, Vy, Vz] if relay
-                    else [P, P, P, Vx, Vx, Vy, Vz])
+        # P[i-1] comes from the in-kernel VMEM relay, not an HBM stream
+        operands = [P, P, Vx, Vx, Vy, Vz]
         in_specs = [
-            spec((1, ny, nz), lambda i: (jnp.maximum(i - 1, 0), 0, 0)),
             spec((1, ny, nz), lambda i: (i, 0, 0)),
             spec((1, ny, nz), lambda i: (jnp.minimum(i + 1, nx - 1), 0, 0)),
             spec((1, ny, nz), lambda i: (i, 0, 0)),
@@ -459,8 +447,6 @@ def acoustic_step_exchange_pallas(state, gg, modes, *, rho, K, dt,
             spec((1, ny + 1, nz), lambda i: (i, 0, 0)),
             spec((1, ny, nz + 1), lambda i: (i, 0, 0)),
         ]
-        if relay:
-            del in_specs[0]   # P[i-1]: replaced by the VMEM relay
 
     from .pallas_common import add_recv_operands, out_shape_with_vma
 
@@ -518,29 +504,23 @@ def acoustic_step_exchange_pallas(state, gg, modes, *, rho, K, dt,
             **_sequential_grid_params(interpret),
         )(*operands)
     else:
+        from jax.experimental.pallas import tpu as pltpu
+
+        from .pallas_stencil import _sequential_grid_params
+
         kernel = partial(
-            _wave_kernel, nx=nx, modes=kmod, relay=relay,
+            _wave_kernel, nx=nx, modes=kmod,
             cx=cx, cy=cy, cz=cz, dtK=dtK, dx=dxp, dy=dyp, dz=dzp,
             self_ols=self_ols)
-        if relay:
-            from jax.experimental.pallas import tpu as pltpu
-
-            from .pallas_stencil import _sequential_grid_params
-
-            extra = dict(
-                scratch_shapes=[pltpu.VMEM((2, ny, nz), P.dtype)],
-                **_sequential_grid_params(interpret),
-            )
-        else:
-            extra = {}
         Pn, Vxn, Vyn, Vzn = pl.pallas_call(
             kernel,
             grid=(nx,),
             in_specs=in_specs,
             out_specs=out_specs,
             out_shape=out_shapes,
+            scratch_shapes=[pltpu.VMEM((2, ny, nz), P.dtype)],
             interpret=interpret,
-            **extra,
+            **_sequential_grid_params(interpret),
         )(*operands)
 
     # The kernel wrote Vx planes 0..nx-1 of the (nx+1)-plane output; plane

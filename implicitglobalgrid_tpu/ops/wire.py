@@ -57,8 +57,7 @@ from .precision import (
 )
 
 __all__ = ["WireSchema", "slab_schema", "schema_for_fields",
-           "CommCadence", "resolve_comm_every",
-           "WireStagePolicy", "resolve_wire_stage", "StagedWireSchema"]
+           "CommCadence", "resolve_comm_every"]
 
 
 # ---------------------------------------------------------------------------
@@ -203,133 +202,6 @@ def resolve_comm_every(comm_every=None) -> CommCadence:
         seen.add(dim)
         per_dim[dim] = _parse_cadence_k(k)
     return CommCadence(tuple(per_dim))
-
-
-# ---------------------------------------------------------------------------
-# per-axis topology staging (the IGG_HALO_WIRE_STAGE knob's resolved form)
-# ---------------------------------------------------------------------------
-
-# off spellings shared with the wire-dtype family, plus the explicit
-# "keep the flat pair" name
-_STAGE_OFF = (None, "", "0", "off", "none", "flat", "false")
-_STAGE_ON = ("staged", "hier", "hierarchical", "1", "on", "true")
-
-
-def _parse_stage(token) -> bool:
-    if isinstance(token, bool):
-        return token
-    if isinstance(token, str):
-        token = token.strip().lower()
-    if token in _STAGE_OFF:
-        return False
-    if token in _STAGE_ON:
-        return True
-    raise InvalidArgumentError(
-        f"Unsupported halo wire stage {token!r}; supported: 'staged' "
-        "(hierarchical gather->DCN->scatter) or 'flat'/'off'.")
-
-
-@dataclass(frozen=True)
-class WireStagePolicy:
-    """Resolved PER-MESH-AXIS topology staging: one bool per grid
-    dimension (x, y, z) saying whether that axis's exchange runs the
-    hierarchical ICI-gather -> striped-DCN -> ICI-scatter pipeline
-    instead of the flat ppermute pair (HiCCL hierarchical composition,
-    arXiv:2408.05962). OFF everywhere by default; an axis whose granule
-    layout is degenerate (one granule, or no perpendicular ICI axis to
-    fold over — `parallel.topology.staged_wire_layout` returns ``None``)
-    silently keeps the flat pair, so the policy is always safe to
-    request. The canonical string form round-trips through
-    `resolve_wire_stage` (``"staged"`` when uniform-on, else e.g.
-    ``"z:staged"``; ``"off"`` when nothing is staged)."""
-
-    per_dim: tuple
-
-    def for_dim(self, dim: int) -> bool:
-        """Whether grid dimension ``dim`` is staged (dims beyond the
-        policy — e.g. 2-D fields' missing z — stay flat)."""
-        if 0 <= int(dim) < len(self.per_dim):
-            return bool(self.per_dim[int(dim)])
-        return False
-
-    @property
-    def any_staged(self) -> bool:
-        return any(self.per_dim)
-
-    @property
-    def staged_dims(self) -> tuple:
-        """Grid dims requesting the staged pipeline, ascending."""
-        return tuple(d for d, s in enumerate(self.per_dim) if s)
-
-    def __str__(self) -> str:
-        if not self.any_staged:
-            return "off"
-        if all(self.per_dim):
-            return "staged"
-        return ",".join(f"{_DIM_NAMES[d]}:staged"
-                        for d in self.staged_dims)
-
-    def __repr__(self) -> str:
-        return f"WireStagePolicy({self})"
-
-
-def resolve_wire_stage(wire_stage=None):
-    """Resolve the requested topology staging to a `WireStagePolicy`, or
-    ``None`` for the flat wire everywhere (the default).
-
-    ``wire_stage=None`` consults ``IGG_HALO_WIRE_STAGE``; an explicit
-    argument (incl. ``"off"``) wins over the environment. Accepted forms
-    (the `resolve_wire_dtype` spelling family):
-
-    - ``"staged"`` — every mesh axis with a usable granule layout stages;
-    - a per-axis spec ``"z:staged"`` / ``"z:staged,x:flat"`` (axes
-      ``x``/``y``/``z`` or ``gx``/``gy``/``gz``; unnamed axes stay flat);
-    - a ``{axis: "staged"|bool}`` mapping, or a `WireStagePolicy`."""
-    import os
-
-    if wire_stage is None:
-        wire_stage = os.environ.get("IGG_HALO_WIRE_STAGE")
-    if isinstance(wire_stage, WireStagePolicy):
-        return wire_stage if wire_stage.any_staged else None
-    if isinstance(wire_stage, str):
-        wire_stage = wire_stage.strip().lower()
-    if wire_stage in _STAGE_OFF:
-        return None
-    if isinstance(wire_stage, dict):
-        items = list(wire_stage.items())
-    elif isinstance(wire_stage, str) and ":" in wire_stage:
-        items = []
-        for part in wire_stage.split(","):
-            part = part.strip()
-            if not part:
-                continue
-            if ":" not in part:
-                raise InvalidArgumentError(
-                    f"Per-axis wire stage spec {wire_stage!r}: entry "
-                    f"{part!r} must be '<axis>:staged' (e.g. 'z:staged').")
-            axis, st = part.split(":", 1)
-            items.append((axis, st))
-    else:
-        return (WireStagePolicy((True,) * 3)
-                if _parse_stage(wire_stage) else None)
-
-    per_dim = [False, False, False]
-    seen = set()
-    for axis, st in items:
-        key = str(axis).strip().lower()
-        dim = _AXIS_TOKENS.get(key)
-        if dim is None:
-            raise InvalidArgumentError(
-                f"Unknown mesh axis {axis!r} in wire stage spec (use "
-                "x/y/z or gx/gy/gz).")
-        if dim in seen:
-            raise InvalidArgumentError(
-                f"Mesh axis {axis!r} named twice in wire stage spec.")
-        seen.add(dim)
-        per_dim[dim] = _parse_stage(st)
-    if not any(per_dim):
-        return None
-    return WireStagePolicy(tuple(per_dim))
 
 
 @dataclass(frozen=True)
@@ -538,117 +410,3 @@ def schema_for_fields(dim: int, shapes, hws, state_dtype,
         s[dim] = int(hw)
         slab_shapes.append(tuple(s))
     return slab_schema(dim, slab_shapes, state_dtype, fmt, members=members)
-
-
-# ---------------------------------------------------------------------------
-# the staged (hierarchical) wire: one packed payload, three routed stages
-# ---------------------------------------------------------------------------
-
-@dataclass(frozen=True)
-class StagedWireSchema:
-    """One staged axis's three-stage wire program: the flat `WireSchema`
-    payload (pack/unpack are UNCHANGED — bit-identity by construction)
-    plus the `parallel.topology.StagedWireLayout` routes it travels:
-
-    1. **gather** — ``fold - 1`` pipelined ppermute shifts along the
-       gather (ICI) axis collect every sending plane's packed slab onto
-       the per-granule leaders (payload: one packed buffer per hop);
-    2. **dcn** — ONE ppermute per direction whose pairs are leader ->
-       leader across the granule boundary, payload ``fold`` concatenated
-       buffers (the striped transfer — per-DCN-link message count drops
-       by the ICI fold);
-    3. **scatter** — ``fold - 1`` reverse shifts fan the pieces back out
-       on the far side (payload: one packed buffer per hop).
-
-    Pairs that never cross a granule boundary keep the flat single-axis
-    ppermute (the ``intra`` stage). Quantized payloads need no special
-    casing: the per-slab f32 scales ride in-band inside the packed buffer
-    through all three stages.
-
-    This object is the ONE byte/route ledger for the staged axis —
-    `ops.halo._plan_from_sig`, `telemetry.predict_step`, and
-    `analysis.contracts` all read the same `stage_table`, so the plan,
-    the oracle, and the compiled-program audit cannot drift."""
-
-    schema: WireSchema
-    layout: object  # parallel.topology.StagedWireLayout
-
-    @property
-    def fold(self) -> int:
-        return int(self.layout.fold)
-
-    @property
-    def payload_bytes(self) -> int:
-        """Bytes of ONE packed buffer (the gather/scatter/intra hop
-        payload); the DCN stripe carries ``fold`` of these."""
-        return self.schema.payload_bytes
-
-    @property
-    def dcn_payload_bytes(self) -> int:
-        return self.schema.payload_bytes * self.fold
-
-    def stage_table(self) -> tuple:
-        """Per-(direction, stage) records — the staged ledger every
-        reasoning layer prices. Each record:
-        ``{"direction", "stage", "ops", "pairs", "payload_bytes",
-        "wire_bytes"}`` where ``pairs`` counts the LINEARIZED
-        source-target pairs of one compiled collective-permute and
-        ``wire_bytes = ops * pairs * payload_bytes`` (absolute, whole
-        mesh)."""
-        out = []
-        pb = self.payload_bytes
-        f = self.fold
-        for d in self.layout.directions:
-            if d.intra_pairs_lin:
-                out.append({"direction": d.name, "stage": "intra",
-                            "ops": 1, "pairs": len(d.intra_pairs_lin),
-                            "payload_bytes": pb,
-                            "wire_bytes": pb * len(d.intra_pairs_lin)})
-            if not d.cross_pairs:
-                continue
-            out.append({"direction": d.name, "stage": "gather",
-                        "ops": f - 1, "pairs": len(d.gather_pairs),
-                        "payload_bytes": pb,
-                        "wire_bytes": (f - 1) * pb * len(d.gather_pairs)})
-            out.append({"direction": d.name, "stage": "dcn",
-                        "ops": 1, "pairs": len(d.dcn_pairs),
-                        "payload_bytes": pb * f,
-                        "wire_bytes": pb * f * len(d.dcn_pairs)})
-            out.append({"direction": d.name, "stage": "scatter",
-                        "ops": f - 1, "pairs": len(d.scatter_pairs),
-                        "payload_bytes": pb,
-                        "wire_bytes": (f - 1) * pb * len(d.scatter_pairs)})
-        return tuple(out)
-
-    @property
-    def ppermute_ops(self) -> int:
-        """Total collective-permute ops one exchange round issues on this
-        axis (both directions, every stage) — the number the contract
-        proves and `predict_step` prices latency against."""
-        return sum(r["ops"] for r in self.stage_table())
-
-    @property
-    def wire_bytes(self) -> int:
-        """Total ABSOLUTE wire bytes of one exchange round on this axis
-        across the whole mesh (cf. the flat plan's per-line bytes — the
-        gather/scatter pair sets span the perpendicular plane, so the
-        per-line abstraction does not factor)."""
-        return sum(r["wire_bytes"] for r in self.stage_table())
-
-    @property
-    def dcn_pair_count(self) -> int:
-        """DCN-crossing source-target pairs per round (both directions) —
-        the numerator of the bench's ``staged_dcn_msgs_ratio``."""
-        return sum(r["pairs"] for r in self.stage_table()
-                   if r["stage"] == "dcn")
-
-    def flat_dcn_pair_count(self) -> int:
-        """The flat wire's DCN-crossing pair count for the same axis:
-        every granule-crossing single-axis pair replicated over every
-        perpendicular line."""
-        n_lines = 1
-        for d, n in enumerate(self.layout.dims):
-            if d != self.layout.dim:
-                n_lines *= int(n)
-        return sum(len(d.cross_pairs) for d in self.layout.directions) \
-            * n_lines

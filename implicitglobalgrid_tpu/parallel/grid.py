@@ -198,28 +198,6 @@ def init_global_grid(
     mesh = build_mesh(tuple(int(d) for d in dims), devices, reorder,
                       cfg.dcn_axes)
 
-    # DCN granule shape — how many ICI granules the mesh spans per axis.
-    # Real multi-slice pools derive it from the pool's slice structure
-    # (the same factorization `arrange_devices` laid the mesh out with);
-    # single-granule dev boxes can declare it via IGG_TPU_DCN_GRANULES so
-    # the topology-staged wire and its pricing/contract layers see the
-    # pod's granule shape.
-    from .mesh import _dcn_factorization, _slice_groups
-
-    groups = _slice_groups(list(devices)[: int(np.prod(dims))])
-    if len(groups) > 1 and cfg.dcn_axes:
-        dcn_granules, _ = _dcn_factorization(dims, cfg.dcn_axes,
-                                             len(groups))
-    else:
-        dcn_granules = tuple(int(g) for g in cfg.dcn_granules)
-        for d in range(NDIMS):
-            if dcn_granules[d] > 1 and int(dims[d]) % dcn_granules[d]:
-                raise IncoherentArgumentError(
-                    f"IGG_TPU_DCN_GRANULES: {dcn_granules[d]} granule(s) "
-                    f"along {'xyz'[d]} do not divide the axis' "
-                    f"{int(dims[d])} shard(s)."
-                )
-
     me = jax.process_index()
     # This controller's Cartesian coords — its first addressable device's
     # mesh position (reference per-rank `Cart_coords`,
@@ -241,7 +219,6 @@ def init_global_grid(
             [(resolved_type == "tpu") if v is None else v for v in cfg.use_pallas],
             dtype=bool),
         dcn_axes=cfg.dcn_axes, quiet=bool(quiet),
-        dcn_granules=dcn_granules,
     )
     set_global_grid(gg)
 

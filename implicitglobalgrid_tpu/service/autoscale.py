@@ -497,13 +497,12 @@ class Autoscaler:
         dtype = next(iter(run.state.values())).dtype
         tuned = run.tuned
         knobs = dict(comm_every=1, overlap=False, coalesce=None,
-                     wire_dtype=None, wire_stage=None)
+                     wire_dtype=None)
         if tuned is not None:
             knobs = dict(comm_every=tuned.comm_every,
                          overlap=bool(tuned.overlap),
                          coalesce=tuned.coalesce,
-                         wire_dtype=tuned.wire_dtype,
-                         wire_stage=tuned.wire_stage)
+                         wire_dtype=tuned.wire_dtype)
         # the boundary has NO current grid — resolve the profile from the
         # job's own grid instead of the (uninitialized) global one
         dt = getattr(job.gg, "device_type", None)

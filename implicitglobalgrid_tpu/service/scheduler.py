@@ -753,13 +753,12 @@ class MeshScheduler:
             jax.ShapeDtypeStruct(v.shape[1:] if E else v.shape, v.dtype)
             for v in state.values())
         knobs = dict(comm_every=1, overlap=False, coalesce=None,
-                     wire_dtype=None, wire_stage=None)
+                     wire_dtype=None)
         if tuned is not None:
             knobs = dict(comm_every=tuned.comm_every,
                          overlap=bool(tuned.overlap),
                          coalesce=tuned.coalesce,
-                         wire_dtype=tuned.wire_dtype,
-                         wire_stage=tuned.wire_stage)
+                         wire_dtype=tuned.wire_dtype)
         try:
             pred = predict_step(spec.model, fields,
                                 profile=default_machine_profile(),
@@ -804,9 +803,8 @@ class MeshScheduler:
         ``ensemble`` shapes the state — only re-admission could change
         them. ``wire_dtype`` is frozen too: a re-tune must never switch
         a tenant onto a lossy wire mid-run (trajectories stay
-        bit-identical to the solo reference). What IS searched are the
-        bit-exact transport knobs — halo coalescing and the
-        topology-staged wire. Journals ``job_retuned`` (or
+        bit-identical to the solo reference). What IS searched is the
+        bit-exact transport knob — halo coalescing. Journals ``job_retuned`` (or
         ``job_retune_failed``) and re-prices the driver so deadline
         slack tracks the tuned geometry. Returns True when a config was
         applied."""
@@ -822,13 +820,12 @@ class MeshScheduler:
         run = job.run
         tuned = run.tuned
         cur = dict(comm_every=1, overlap=False, coalesce=True,
-                   wire_dtype=None, wire_stage=None)
+                   wire_dtype=None)
         if tuned is not None:
             cur = dict(comm_every=tuned.comm_every,
                        overlap=bool(tuned.overlap),
                        coalesce=tuned.coalesce,
-                       wire_dtype=tuned.wire_dtype,
-                       wire_stage=tuned.wire_stage)
+                       wire_dtype=tuned.wire_dtype)
         n = tuple(int(v) for v in gg.nxyz)
         grid = dict(nx=n[0], ny=n[1], nz=n[2],
                     dimx=int(gg.dims[0]), dimy=int(gg.dims[1]),
@@ -844,8 +841,6 @@ class MeshScheduler:
                 model, grid, dtype=dtype,
                 comm_every_options=(cur["comm_every"],),
                 wire_dtype_options=(cur["wire_dtype"],),
-                wire_stage_options=tuple(dict.fromkeys(
-                    [cur["wire_stage"], None, "z:staged"])),
                 coalesce_options=tuple(dict.fromkeys(
                     [cur["coalesce"], True, False])),
                 overlap_options=(cur["overlap"],),

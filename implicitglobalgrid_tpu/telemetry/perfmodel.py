@@ -125,10 +125,9 @@ def hierarchical_machine_profile() -> MachineProfile:
     """Canned hierarchical ICI+DCN coefficients (``source="default"``):
     ``gx``/``gy`` at ICI-class rates and ``gz`` at DCN-class rates (an
     order of magnitude less bandwidth, an order of magnitude more launch
-    latency — the multi-slice pod shape the topology-staged wire exists
-    for). Lets the staged-vs-flat pricing, the tuner's staged candidate
-    leg, and the bench's modeled rows run on a dev box whose real links
-    are all one class — the same modeled-rescue pattern as the
+    latency — a multi-slice pod's shape). Lets the per-axis wire knobs
+    (``comm_every``, ``wire_dtype``) be priced on a dev box whose real
+    links are all one class — the same modeled-rescue pattern as the
     comm-avoiding bench rows. Calibrate on the real pod for measured
     coefficients."""
     axes = {"gx": {"GBps": 45.0, "latency_s": 5e-6},
@@ -272,7 +271,7 @@ def _axis_npairs(gg, dim: int) -> int:
 
 def predict_step(model, fields, *, profile: MachineProfile | None = None,
                  comm_every=1, overlap: bool = False,
-                 dims=None, coalesce=None, wire_dtype=None, wire_stage=None,
+                 dims=None, coalesce=None, wire_dtype=None,
                  impl: str = "xla", ensemble: int | None = None) -> dict:
     """Predict one step's cost on the CURRENT grid for stacked ``fields``.
 
@@ -306,20 +305,6 @@ def predict_step(model, fields, *, profile: MachineProfile | None = None,
     (`StepWorkload.groups_for` — the fused Pallas pass may group rounds
     differently, e.g. acoustic's one packed 4-field round).
 
-    ``wire_stage`` prices the topology-staged wire (`ops.halo` — the
-    `resolve_wire_stage` spelling family, e.g. ``"z:staged"``): a staged
-    axis's gather/scatter/intra hops are priced against the GATHER
-    axis's (ICI) link coefficients while its one striped DCN transfer is
-    priced against the staged axis's own (DCN) coefficients — each stage
-    against the link class it actually crosses. The axis's comm record
-    then carries a ``staged`` sub-record with the per-stage seconds, the
-    flat-wire alternative priced on the same coefficients
-    (``flat_s``/``staged_s``/``wins``) and the per-DCN-link message-fold
-    ``dcn_msgs_ratio`` — the staged-vs-flat verdict the auto-tuner's
-    candidate generator reads. When a latency-bound verdict lands on an
-    axis the staging could (or does) fold, ``bound_detail`` names
-    ``wire_stage[z]`` — the knob to turn.
-
     ``ensemble=E`` prices the ENSEMBLE axis (ISSUE 12): E scenario
     members batched through one chunk — compute and wire bytes scale by
     E while the collective LAUNCH count (and so the latency term) stays
@@ -348,10 +333,8 @@ def predict_step(model, fields, *, profile: MachineProfile | None = None,
     bandwidth-bound one wants ``wire_dtype``, a compute-bound one is
     already at the roofline."""
     from ..ops.halo import halo_comm_plan
-    from ..ops.wire import resolve_comm_every, resolve_wire_stage
-    from ..parallel.topology import (
-        check_initialized, global_grid, staged_wire_layout,
-    )
+    from ..ops.wire import resolve_comm_every
+    from ..parallel.topology import check_initialized, global_grid
 
     check_initialized()
     gg = global_grid()
@@ -366,7 +349,6 @@ def predict_step(model, fields, *, profile: MachineProfile | None = None,
         model_name = str(model)
     profile = profile if profile is not None else default_machine_profile()
     cad = resolve_comm_every(comm_every)
-    stg = resolve_wire_stage(wire_stage)
     E = 1
     if ensemble is not None:
         E = int(ensemble)
@@ -387,17 +369,12 @@ def predict_step(model, fields, *, profile: MachineProfile | None = None,
                 f"(exchange group {group}); got {len(fields)}.")
         sub = halo_comm_plan(*(fields[i] for i in group), dims=dims,
                              coalesce=coalesce, wire_dtype=wire_dtype,
-                             ensemble=ensemble, wire_stage=stg)
+                             ensemble=ensemble)
         for axis, rec in sub["axes"].items():
             dst = plan["axes"].setdefault(
                 axis, {"ppermutes": 0, "wire_bytes": 0})
             dst["ppermutes"] += rec["ppermutes"]
             dst["wire_bytes"] += rec["wire_bytes"]
-            if "staged" in rec:  # merge rounds' stage tables (one layout)
-                det = dst.setdefault(
-                    "staged", {k: v for k, v in rec["staged"].items()
-                               if k != "stages"} | {"stages": []})
-                det["stages"].extend(rec["staged"]["stages"])
         for axis, b in sub["local_copy_by_axis"].items():
             plan["local_copy_by_axis"][axis] = (
                 plan["local_copy_by_axis"].get(axis, 0) + b)
@@ -427,79 +404,14 @@ def predict_step(model, fields, *, profile: MachineProfile | None = None,
         # OWN cadence (the k_d-wide slabs are already in the plan's
         # bytes, so per-step wire bytes stay flat while launches divide)
         k_ax = cad.for_dim(axis_dims[axis])
-        if "staged" in rec:
-            # hierarchical three-stage pricing: every stage against the
-            # link class it actually crosses — gather/scatter/intra hops
-            # on the GATHER axis's (ICI) coefficients, the one striped
-            # transfer on this (DCN) axis's own. Each stage-table entry
-            # is one direction; the two directions' concurrency folds
-            # into a pair (ops/2), same convention as the flat pair.
-            det = rec["staged"]
-            ici = profile.axis(det["gather_axis"])
-            lat_s = wire_s = flat_lat = flat_wire = per_link = 0.0
-            stage_s: dict = {}
-            flat_groups = set()
-            for st in det["stages"]:
-                cls = coeff if st["stage"] == "dcn" else ici
-                pr = st["ops"] / 2.0
-                ls = pr * float(cls.get("latency_s", 0.0)) / k_ax
-                ws = pr * st["payload_bytes"] \
-                    / (float(cls["GBps"]) * 1e9) / k_ax
-                lat_s += ls
-                wire_s += ws
-                per_link += pr * st["payload_bytes"]
-                stage_s[st["stage"]] = (
-                    stage_s.get(st["stage"], 0.0) + ls + ws)
-                if st["stage"] in ("gather", "intra") \
-                        and st["group"] not in flat_groups:
-                    # the flat alternative on THIS axis's link class: the
-                    # fold devices of a granule share ONE physical DCN
-                    # bundle per granule-pair, so the flat pair's fold
-                    # messages SERIALIZE through it — M*lat + M*slab/bw
-                    flat_groups.add(st["group"])
-                    flat_lat += det["fold"] \
-                        * float(coeff.get("latency_s", 0.0)) / k_ax
-                    flat_wire += det["fold"] * st["payload_bytes"] \
-                        / (float(coeff["GBps"]) * 1e9) / k_ax
-            staged_s = lat_s + wire_s
-            flat_s = flat_lat + flat_wire
-            comm[axis] = {
-                "ppermute_pairs": pairs, "per_link_bytes": per_link,
-                "comm_every": k_ax,
-                "latency_s": lat_s, "wire_s": wire_s,
-                "s": staged_s,
-                "staged": {
-                    "fold": det["fold"],
-                    "gather_axis": det["gather_axis"],
-                    "dcn_pairs": det["dcn_pairs"],
-                    "flat_dcn_pairs": det["flat_dcn_pairs"],
-                    "dcn_msgs_ratio": (det["flat_dcn_pairs"]
-                                       / max(1, det["dcn_pairs"])),
-                    "stage_s": stage_s,
-                    "staged_s": staged_s,
-                    "flat_s": flat_s,
-                    "wins": staged_s < flat_s,
-                },
-            }
-            lat_total += lat_s
-            wire_total += wire_s
-            continue
         npairs = _axis_npairs(gg, axis_dims[axis])
         per_link = (rec["wire_bytes"] / npairs) if npairs else 0.0
-        # a flat exchange on a granule-crossing axis funnels the fold
-        # devices' messages through ONE physical DCN bundle per
-        # granule-pair — they serialize: M*lat + M*slab/bw (the cost the
-        # topology-staged wire folds back to 1 message per bundle)
-        lay = staged_wire_layout(gg, axis_dims[axis])
-        mult = int(lay.fold) if lay is not None else 1
-        lat_s = pairs * mult * float(coeff.get("latency_s", 0.0)) / k_ax
-        wire_s = per_link * mult / (float(coeff["GBps"]) * 1e9) / k_ax
+        lat_s = pairs * float(coeff.get("latency_s", 0.0)) / k_ax
+        wire_s = per_link / (float(coeff["GBps"]) * 1e9) / k_ax
         comm[axis] = {"ppermute_pairs": pairs, "per_link_bytes": per_link,
                       "comm_every": k_ax,
                       "latency_s": lat_s, "wire_s": wire_s,
                       "s": lat_s + wire_s}
-        if mult > 1:
-            comm[axis]["dcn_msgs_per_link"] = mult
         lat_total += lat_s
         wire_total += wire_s
     # self-neighbor local slab swaps never touch the wire: they are HBM
@@ -543,20 +455,12 @@ def predict_step(model, fields, *, profile: MachineProfile | None = None,
         # ("comm_every[z]"), not an undifferentiated global setting
         dom = max(comm, key=lambda a: comm[a]["latency_s"])
         detail = f"comm_every[{'xyz'[axis_dims[dom]]}]"
-        if "staged" in comm[dom]:
-            # the staged wire's own launches dominate: name its knob
-            detail = f"wire_stage[{'xyz'[axis_dims[dom]]}]"
-        elif staged_wire_layout(gg, axis_dims[dom]) is not None:
-            # a flat DCN-crossing axis whose granule geometry supports
-            # staging: the fold IS the latency knob — name it
-            detail = f"wire_stage[{'xyz'[axis_dims[dom]]}]"
     rec = {
         "model": model_name,
         "profile_source": profile.source,
         "local_cells": local_cells,
         "ensemble": E,
         "comm_every": str(cad),
-        "wire_stage": None if stg is None else str(stg),
         "compute": {"flops": flops, "hbm_bytes": hbm_bytes,
                     "flops_s": flops_s, "hbm_s": hbm_s, "s": compute_s},
         "comm": comm,
@@ -576,8 +480,7 @@ def predict_step(model, fields, *, profile: MachineProfile | None = None,
         solo = predict_step(model, fields, profile=profile,
                             comm_every=comm_every, overlap=overlap,
                             dims=dims, coalesce=coalesce,
-                            wire_dtype=wire_dtype, wire_stage=stg,
-                            impl=impl)
+                            wire_dtype=wire_dtype, impl=impl)
         rec["per_member_step_s"] = step_s / E
         rec["per_member_comm_s"] = comm_s / E
         rec["per_member_exposed_comm_s"] = exposed / E

@@ -5,8 +5,7 @@ deep-halo ``comm_every`` cadences, interior-first ``overlap``, the
 ensemble axis) and PR 6 built the pricing (`predict_step` over a measured
 `MachineProfile`). What remained was the loop that turns them: this
 module's `tune_config` SEARCHES the model over per-axis ``comm_every`` x
-per-axis ``wire_dtype`` x per-axis ``wire_stage`` (the PR 16
-topology-staged wire) x ``coalesce`` x ``overlap`` x ensemble ``E``,
+per-axis ``wire_dtype`` x ``coalesce`` x ``overlap`` x ensemble ``E``,
 VALIDATES the top candidates with short measured calibration runs
 (min-of-reps two-point windows — the same estimator
 `calibrate_machine` uses), and persists the winning `TunedConfig` JSON
@@ -62,8 +61,7 @@ class TunedConfig:
     The knobs are exactly the surface the runtime applies per job:
     ``comm_every`` (canonical per-axis cadence string), ``wire_dtype``
     (canonical per-axis wire policy, or ``None`` = exact),
-    ``wire_stage`` (canonical per-axis topology-staged wire policy, or
-    ``None`` = flat), ``coalesce``, ``overlap``, and ``ensemble``
+    ``coalesce``, ``overlap``, and ``ensemble``
     (``None`` = solo). ``predicted_step_s``
     is the oracle's per-(member-)step price; ``measured_step_s`` /
     ``baseline_step_s`` are the calibration-run numbers when the tuner
@@ -77,7 +75,6 @@ class TunedConfig:
     model: str
     comm_every: str = "1"
     wire_dtype: str | None = None
-    wire_stage: str | None = None
     coalesce: bool = True
     overlap: bool = False
     ensemble: int | None = None
@@ -93,7 +90,6 @@ class TunedConfig:
         """The applied-surface subset, as one dict."""
         return {"comm_every": self.comm_every,
                 "wire_dtype": self.wire_dtype,
-                "wire_stage": self.wire_stage,
                 "coalesce": self.coalesce, "overlap": self.overlap,
                 "ensemble": self.ensemble}
 
@@ -101,21 +97,16 @@ class TunedConfig:
         """The environment-variable form of the trace-time knobs — what
         the driver/scheduler scope around a tuned job's compiles
         (``IGG_COMM_EVERY`` / ``IGG_HALO_WIRE_DTYPE`` /
-        ``IGG_HALO_COALESCE``, plus ``IGG_HALO_WIRE_STAGE`` when the
-        tuner selected staging; ``overlap`` and ``ensemble`` are
+        ``IGG_HALO_COALESCE``; ``overlap`` and ``ensemble`` are
         structural and applied at setup time instead)."""
-        env = {"IGG_COMM_EVERY": str(self.comm_every),
-               "IGG_HALO_WIRE_DTYPE": (self.wire_dtype or "off"),
-               "IGG_HALO_COALESCE": "1" if self.coalesce else "0"}
-        if self.wire_stage is not None:
-            env["IGG_HALO_WIRE_STAGE"] = str(self.wire_stage)
-        return env
+        return {"IGG_COMM_EVERY": str(self.comm_every),
+                "IGG_HALO_WIRE_DTYPE": (self.wire_dtype or "off"),
+                "IGG_HALO_COALESCE": "1" if self.coalesce else "0"}
 
     def to_json(self) -> dict:
         return {"version": _TUNED_VERSION, "model": self.model,
                 "comm_every": self.comm_every,
                 "wire_dtype": self.wire_dtype,
-                "wire_stage": self.wire_stage,
                 "coalesce": self.coalesce, "overlap": self.overlap,
                 "ensemble": self.ensemble,
                 "predicted_step_s": self.predicted_step_s,
@@ -129,12 +120,18 @@ class TunedConfig:
     def from_json(cls, rec) -> "TunedConfig":
         if isinstance(rec, (str, bytes)):
             rec = json.loads(rec)
+        if isinstance(rec, dict) and rec.get("wire_stage") is not None:
+            # running it flat would not be the configuration that was
+            # tuned: refuse instead of silently dropping the knob
+            raise InvalidArgumentError(
+                f"TunedConfig.from_json: the record selects the staged "
+                f"halo wire (wire_stage={rec['wire_stage']!r}), which was "
+                "removed; re-tune for the flat wire.")
         try:
             return cls(
                 model=str(rec["model"]),
                 comm_every=str(rec.get("comm_every", "1")),
                 wire_dtype=rec.get("wire_dtype"),
-                wire_stage=rec.get("wire_stage"),
                 coalesce=bool(rec.get("coalesce", True)),
                 overlap=bool(rec.get("overlap", False)),
                 ensemble=(None if rec.get("ensemble") is None
@@ -185,7 +182,9 @@ def tuned_config_path(profile_path, model: str) -> str:
 def resolve_tuned(tuned) -> TunedConfig | None:
     """Normalize every accepted `RunSpec.tuned` form: ``None`` passes
     through, a `TunedConfig` is returned as-is, a dict parses as its
-    JSON record, and a string/path loads the persisted file."""
+    JSON record, and a string/path loads the persisted file. A record
+    that selects the removed staged halo wire is refused
+    (`TunedConfig.from_json`)."""
     if tuned is None or isinstance(tuned, TunedConfig):
         return tuned
     if isinstance(tuned, dict):
@@ -354,7 +353,6 @@ def _measure_candidate(model: str, cand: dict, grid_kw: dict, dtype,
     try:
         with _scoped_env({
                 "IGG_HALO_WIRE_DTYPE": cand["wire_dtype"] or "off",
-                "IGG_HALO_WIRE_STAGE": cand.get("wire_stage") or "off",
                 "IGG_HALO_COALESCE": "1" if cand["coalesce"] else "0"}):
             state, factory, per_unit = _build_runner(model, cand, dtype)
 
@@ -382,7 +380,6 @@ def _default_comm_every_options(dims, periods) -> tuple:
 def tune_config(model: str, grid: dict, profile=None, *,
                 dtype="float32",
                 comm_every_options=None, wire_dtype_options=(None,),
-                wire_stage_options=(None,),
                 coalesce_options=(True,), overlap_options=(False,),
                 ensemble_options=(None,),
                 top_k: int = 2, measure: bool = True,
@@ -412,16 +409,7 @@ def tune_config(model: str, grid: dict, profile=None, *,
     (epoch retained — its compiled caches survive) and restored on
     exit; candidate grids are initialized and finalized internally.
     Returns the winning `TunedConfig` (persisted when ``path`` or a
-    profile path was given).
-
-    ``wire_stage_options`` adds the topology-staged wire (PR 16) to the
-    search: a ``"z:staged"`` candidate reroutes the z exchange as ICI
-    leader-gather -> one striped DCN transfer per granule pair -> ICI
-    scatter. It is priced per stage against each stage's own link class,
-    so it only ranks ahead of flat where the profile is genuinely
-    hierarchical — and with ``measure=True`` it must ALSO win the
-    measured validation leg before `tune_config` selects it (model and
-    measurement have to agree)."""
+    profile path was given)."""
     from ..models.common import resolve_comm_every
     from ..parallel import topology as top
     from ..parallel.grid import finalize_global_grid, init_global_grid
@@ -446,33 +434,24 @@ def tune_config(model: str, grid: dict, profile=None, *,
     if comm_every_options is None:
         comm_every_options = _default_comm_every_options(dims, periods)
 
-    # candidate space (canonical cadence/stage strings de-dup spellings)
-    from ..ops.wire import resolve_wire_stage
-
+    # candidate space (canonical cadence strings de-dup spellings)
     cands = []
     seen = set()
-    for ce, wd, ws, co, ov, E in itertools.product(
-            comm_every_options, wire_dtype_options, wire_stage_options,
+    for ce, wd, co, ov, E in itertools.product(
+            comm_every_options, wire_dtype_options,
             coalesce_options, overlap_options, ensemble_options):
         cad = resolve_comm_every(ce)
         if cad.deep and ov:
             continue  # the deep runners ignore overlap — not a real combo
-        # canonicalize the stage spelling without the env fallback
-        # (resolve_wire_stage(None) reads IGG_HALO_WIRE_STAGE — a tune
-        # candidate's None means FLAT, not "whatever the env says")
-        stg = None if ws is None else resolve_wire_stage(ws)
-        stg = None if stg is None else str(stg)
-        key = (str(cad), wd, stg, bool(co), bool(ov),
+        key = (str(cad), wd, bool(co), bool(ov),
                None if E is None else int(E))
         if key in seen:
             continue
         seen.add(key)
         cands.append({"comm_every": str(cad), "wire_dtype": wd,
-                      "wire_stage": stg,
                       "coalesce": bool(co), "overlap": bool(ov),
                       "ensemble": None if E is None else int(E)})
     default_cand = {"comm_every": "1", "wire_dtype": None,
-                    "wire_stage": None,
                     "coalesce": True, "overlap": False, "ensemble": None}
     if not any(c == default_cand for c in cands):
         cands.insert(0, dict(default_cand))
@@ -511,7 +490,6 @@ def tune_config(model: str, grid: dict, profile=None, *,
                         comm_every=c["comm_every"],
                         overlap=c["overlap"], coalesce=c["coalesce"],
                         wire_dtype=c["wire_dtype"],
-                        wire_stage=c["wire_stage"],
                         ensemble=c["ensemble"])
                     E = c["ensemble"] or 1
                     priced.append((pred["step_s"] / E, c, pred, dict(kw)))
@@ -561,7 +539,6 @@ def tune_config(model: str, grid: dict, profile=None, *,
         model=model,
         comm_every=win_c["comm_every"],
         wire_dtype=win_c["wire_dtype"],
-        wire_stage=win_c["wire_stage"],
         coalesce=win_c["coalesce"],
         overlap=win_c["overlap"],
         ensemble=win_c["ensemble"],

@@ -504,8 +504,7 @@ def _cli(argv=None) -> int:
     pdc.add_argument("--indent", type=int, default=2)
     tu = sub.add_parser(
         "tune", help="closed-loop auto-tuner: search the cost model over "
-                     "comm_every/wire_dtype/wire_stage/coalesce/overlap/"
-                     "ensemble, "
+                     "comm_every/wire_dtype/coalesce/overlap/ensemble, "
                      "validate with short measured runs, persist the "
                      "winning TunedConfig")
     tu.add_argument("model",
@@ -542,13 +541,6 @@ def _cli(argv=None) -> int:
                          "'off,z:int8,z:int8,x:f32' — entries with ':' "
                          "are kept whole per policy segment; use ';' to "
                          "separate multi-axis policies)")
-    tu.add_argument("--wire-stage-options", default=None,
-                    help="comma-separated topology-staged wire "
-                         "candidates (e.g. 'off,z:staged'): 'off' is the "
-                         "flat wire, 'z:staged' routes the z exchange "
-                         "ICI-gather -> striped DCN -> ICI-scatter "
-                         "(needs declared DCN granules — multi-slice or "
-                         "IGG_TPU_DCN_GRANULES)")
     tu.add_argument("--ensemble-options", default=None,
                     help="comma-separated ensemble sizes to sweep "
                          "(e.g. '1,4,8'; 1 = solo)")
@@ -576,11 +568,10 @@ def _cli(argv=None) -> int:
                      help="skip measurement and emit a canned profile "
                           "instead: 'hierarchical' is the ICI+DCN "
                           "link-class preset (fast/low-latency x,y; "
-                          "slow/high-latency DCN z) that makes "
-                          "staged-vs-flat wire pricing and the bench "
-                          "modeled rows meaningful on a CPU dev box "
-                          "without a pod (host-only: no grid, no "
-                          "accelerator)")
+                          "slow/high-latency DCN z) that makes per-axis "
+                          "wire pricing and the bench modeled rows "
+                          "meaningful on a CPU dev box without a pod "
+                          "(host-only: no grid, no accelerator)")
     cal.add_argument("--indent", type=int, default=2)
     rs = sub.add_parser(
         "reshard", help="on-device elastic resharding: print a transfer "
@@ -679,14 +670,6 @@ def _cli(argv=None) -> int:
                           "quantized (int8/int4), or a per-axis policy "
                           "like z:int8,x:f32 (audits the narrowing "
                           "reached each axis's wire)")
-    aud.add_argument("--wire-stage", default=None,
-                     help="topology-staged wire policy the exchange was "
-                          "built with (e.g. z:staged): the staged axis's "
-                          "exchange is audited as ICI leader-gather -> "
-                          "one striped DCN transfer per granule pair -> "
-                          "ICI scatter, against the multi-stage contract "
-                          "(per-stage permute counts, routes, and "
-                          "payload bytes)")
     aud.add_argument("--lowered", action="store_true",
                      help="audit the pre-backend StableHLO instead of "
                           "backend-optimized HLO (where wire downcasts "
@@ -1160,10 +1143,6 @@ def _cli_tune(args) -> int:
         kw["wire_dtype_options"] = tuple(
             None if w.lower() in ("off", "none", "") else w
             for w in _split(args.wire_options))
-    if args.wire_stage_options:
-        kw["wire_stage_options"] = tuple(
-            None if w.lower() in ("off", "none", "flat", "") else w
-            for w in _split(args.wire_stage_options))
     if args.ensemble_options:
         kw["ensemble_options"] = tuple(
             None if int(e) <= 1 else int(e)
@@ -1563,7 +1542,6 @@ def _cli_audit(args) -> int:
             for model in args.models:
                 reports.append((model, audit_model(
                     model, impl=args.impl, wire_dtype=args.wire_dtype,
-                    wire_stage=args.wire_stage,
                     crosscheck=not args.no_crosscheck,
                     optimized=not args.lowered,
                     ensemble=args.ensemble,

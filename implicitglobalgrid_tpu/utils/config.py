@@ -21,13 +21,11 @@ an explanatory error (mirroring the reference's legacy-var rejection at
   halo pack path.
 - ``IGG_TPU_DCN_AXES``: comma-separated mesh axes ("x","y","z") that cross
   slice boundaries (DCN) in a multi-slice deployment.
-- ``IGG_TPU_DCN_GRANULES``: per-axis DCN granule counts (``"z:2"`` /
-  ``"x:2,z:2"``) — how many ICI granules (slices/hosts) the mesh spans
-  along each axis. On real multi-slice pools `init_global_grid` derives
-  this from the device pool's slice structure; the env var declares it
-  for single-granule dev boxes (CPU meshes, contract fixtures) so the
-  topology-staged wire (`IGG_HALO_WIRE_STAGE`) and its pricing/contract
-  layers see the granule shape they would see on the pod.
+
+The variables of removed features (the staged halo wire and the kernel
+A/B toggles) are rejected the same way, each with what now happens, so a
+job script that still sets one fails loudly instead of being silently
+ignored (`_REJECTED_ENV_VARS`).
 """
 
 from __future__ import annotations
@@ -44,6 +42,10 @@ _REJECTED_ENV_VARS = {
     "IGG_ROCMAWARE_MPI": "GPU-aware MPI does not apply on TPU: ICI collectives always move data HBM-to-HBM.",
     "IGG_LOOPVECTORIZATION": "Environment variable IGG_LOOPVECTORIZATION is not supported. Use IGG_USE_PALLAS instead.",
     "IGG_USE_POLYESTER": "Environment variable IGG_USE_POLYESTER does not apply on TPU. Use IGG_USE_PALLAS instead.",
+    "IGG_HALO_WIRE_STAGE": "the topology-staged halo wire was removed; every axis exchanges on the flat ppermute wire.",
+    "IGG_TPU_DCN_GRANULES": "DCN granule declarations were removed with the staged halo wire; every axis exchanges on the flat ppermute wire.",
+    "IGG_MP_HANDOFF": "the window-handoff toggle was removed; the multi-plane kernels hand off between windows whenever the shape allows (3 or more windows).",
+    "IGG_PLANE_RELAY": "the plane-relay toggle was removed; the plane-per-program passes always relay the previous plane through VMEM.",
 }
 
 _DIM_SUFFIXES = ("_DIMX", "_DIMY", "_DIMZ")
@@ -67,7 +69,6 @@ class EnvConfig:
     # tri-state per dim: None = unset (resolved at init: True on TPU grids,
     # False elsewhere), True/False = explicit env setting
     dcn_axes: tuple = ()                   # IGG_TPU_DCN_AXES
-    dcn_granules: tuple = (1, 1, 1)        # IGG_TPU_DCN_GRANULES
 
 
 def read_env_config() -> EnvConfig:
@@ -109,45 +110,4 @@ def read_env_config() -> EnvConfig:
                 f"Environment variable IGG_TPU_DCN_AXES: duplicate axis name(s) in {names}."
             )
         cfg.dcn_axes = names
-
-    gran = os.environ.get("IGG_TPU_DCN_GRANULES", "")
-    if gran:
-        per_dim = [1, 1, 1]
-        seen = set()
-        for part in gran.split(","):
-            part = part.strip()
-            if not part:
-                continue
-            if ":" not in part:
-                raise InvalidArgumentError(
-                    f"Environment variable IGG_TPU_DCN_GRANULES: entry {part!r} "
-                    "must be '<axis>:<count>' (e.g. 'z:2')."
-                )
-            axis, cnt = part.split(":", 1)
-            axis = axis.strip()
-            dim = {"x": 0, "y": 1, "z": 2}.get(axis)
-            if dim is None:
-                raise InvalidArgumentError(
-                    f"Environment variable IGG_TPU_DCN_GRANULES: invalid axis name {axis!r}; "
-                    "valid names are x, y, z."
-                )
-            if dim in seen:
-                raise InvalidArgumentError(
-                    f"Environment variable IGG_TPU_DCN_GRANULES: duplicate axis name {axis!r}."
-                )
-            seen.add(dim)
-            try:
-                n = int(cnt.strip())
-            except ValueError as e:
-                raise InvalidArgumentError(
-                    f"Environment variable IGG_TPU_DCN_GRANULES: granule count for axis "
-                    f"{axis!r} must be an integer >= 1, got {cnt!r}."
-                ) from e
-            if n < 1:
-                raise InvalidArgumentError(
-                    f"Environment variable IGG_TPU_DCN_GRANULES: granule count for axis "
-                    f"{axis!r} must be >= 1, got {n}."
-                )
-            per_dim[dim] = n
-        cfg.dcn_granules = tuple(per_dim)
     return cfg

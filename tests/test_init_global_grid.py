@@ -131,6 +131,18 @@ def test_rejected_env_vars(monkeypatch):
         igg.init_global_grid(4, 4, 4, quiet=True)
 
 
+@pytest.mark.parametrize("var", [
+    "IGG_HALO_WIRE_STAGE", "IGG_TPU_DCN_GRANULES",
+    "IGG_MP_HANDOFF", "IGG_PLANE_RELAY",
+])
+def test_removed_env_vars_rejected(monkeypatch, var):
+    # variables of removed features fail loudly instead of being ignored:
+    # a job script that still sets one would otherwise run something else
+    monkeypatch.setenv(var, "1")
+    with pytest.raises(InvalidArgumentError, match=var):
+        igg.init_global_grid(4, 4, 4, quiet=True)
+
+
 def test_select_device_shim():
     igg.init_global_grid(4, 4, 4, quiet=True)
     assert isinstance(igg.select_device(), int)

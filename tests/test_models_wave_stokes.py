@@ -124,14 +124,12 @@ def test_acoustic_pallas_fused_matches_xla(dims, periods, label):
         assert np.allclose(ga, gb, rtol=1e-5, atol=1e-5), (label, name)
 
 
-def test_acoustic_plane_form_relay_matches_xla(monkeypatch):
+def test_acoustic_plane_form_relay_matches_xla():
     """The plane-per-program wave kernel (local nx=10: indivisible by any
-    mp plane count, so the mp gate rejects) with the P[i-1] VMEM relay —
-    and with IGG_PLANE_RELAY=0 restoring the third pressure stream; both
+    mp plane count, so the mp gate rejects) with the P[i-1] VMEM relay
     must match the XLA formulation."""
     from implicitglobalgrid_tpu.ops.pallas_wave import wave_mp_planes
 
-    monkeypatch.delenv("IGG_PLANE_RELAY", raising=False)
     igg.init_global_grid(10, 8, 16, dimx=1, dimy=1, dimz=1,
                          periodx=1, periody=1, periodz=1, quiet=True)
     assert wave_mp_planes((10, 8, 16), np.float32, interpret=True) is None
@@ -141,32 +139,12 @@ def test_acoustic_plane_form_relay_matches_xla(monkeypatch):
     for fa, fb, name in zip(a, b, ("P", "Vx", "Vy", "Vz")):
         ga, gb = np.asarray(igg.gather(fa)), np.asarray(igg.gather(fb))
         assert np.allclose(ga, gb, rtol=1e-5, atol=1e-5), name
-    # flag off IN-EPOCH: retraced (runner keys on kernel_flags) and equal
-    monkeypatch.setenv("IGG_PLANE_RELAY", "0")
-    c = run_acoustic(state, p, 6, nt_chunk=3, impl="pallas_interpret")
-    for fb, fc in zip(b, c):
-        assert np.array_equal(np.asarray(fb), np.asarray(fc))
 
 
-def test_stokes_relay_flag_equivalence(monkeypatch):
-    """The Stokes [i-1]-stream relay: flag on vs off produces identical
-    kernel output (same grid epoch; the runner cache keys on the flag)."""
-    monkeypatch.delenv("IGG_PLANE_RELAY", raising=False)
-    igg.init_global_grid(8, 8, 16, dimx=1, dimy=1, dimz=1,
-                         periodx=1, periody=1, periodz=1, quiet=True)
-    state, p = init_stokes3d(dtype=np.float32)
-    b = run_stokes(state, p, 4, nt_chunk=2, impl="pallas_interpret")
-    monkeypatch.setenv("IGG_PLANE_RELAY", "0")
-    c = run_stokes(state, p, 4, nt_chunk=2, impl="pallas_interpret")
-    for fb, fc in zip(b, c):
-        assert np.array_equal(np.asarray(fb), np.asarray(fc))
-
-
-def test_acoustic_pallas_window_handoff_matches_xla(monkeypatch):
+def test_acoustic_pallas_window_handoff_matches_xla():
     """The acoustic pressure window with the VMEM overlap handoff
     (local nx=12, P=4 -> 3 windows): fused pass equality vs the XLA
     formulation."""
-    monkeypatch.delenv("IGG_MP_HANDOFF", raising=False)
     from implicitglobalgrid_tpu.ops.pallas_wave import wave_mp_planes
 
     igg.init_global_grid(12, 8, 16, dimx=1, dimy=1, dimz=1,

@@ -138,6 +138,21 @@ def test_tuned_config_json_roundtrip(tmp_path):
         igg.load_tuned_config(tmp_path / "missing.json")
 
 
+def test_stored_staged_wire_config_refused(tmp_path):
+    """A stored record that selects the removed staged halo wire is
+    refused, not run flat: that would not be the tuned configuration."""
+    rec = TunedConfig(model="diffusion3d", comm_every="z:2").to_json()
+    rec["wire_stage"] = "z:staged"
+    path = tmp_path / "tuned_diffusion3d.json"
+    path.write_text(json.dumps(rec))
+    for form in (rec, path, str(path)):
+        with pytest.raises(InvalidArgumentError, match="staged halo wire"):
+            resolve_tuned(form)
+    # a flat record (null wire_stage, as older tuners wrote it) still loads
+    rec["wire_stage"] = None
+    assert resolve_tuned(rec).comm_every == "z:2"
+
+
 def test_tune_runspec_scheduler_roundtrip(tmp_path):
     """ISSUE 13 acceptance: tune_config → persisted TunedConfig →
     `RunSpec(tuned=path)` → `MeshScheduler` load-and-apply on admission.
