@@ -43,31 +43,35 @@ def plane_relay(rel_ref, i, cur):
 
 
 def shift_up(a):
-    """out[r] = a[r+1]; last row clones a[-1] (garbage — mask it)."""
+    """out[..., r, :] = a[..., r+1, :]; the last row clones a[..., -1, :]
+    (garbage — mask it). Over the last two axes, as the others."""
     import jax.numpy as jnp
 
-    return jnp.concatenate([a[1:], a[-1:]], axis=0)
+    return jnp.concatenate([a[..., 1:, :], a[..., -1:, :]], axis=-2)
 
 
 def shift_down(a):
-    """out[r] = a[r-1]; first row clones a[0] (garbage — mask it)."""
+    """out[..., r, :] = a[..., r-1, :]; the first row clones a[..., 0, :]
+    (garbage — mask it)."""
     import jax.numpy as jnp
 
-    return jnp.concatenate([a[:1], a[:-1]], axis=0)
+    return jnp.concatenate([a[..., :1, :], a[..., :-1, :]], axis=-2)
 
 
 def shift_left(a):
-    """out[:, c] = a[:, c+1]; last lane clones a[:, -1] (garbage)."""
+    """out[..., c] = a[..., c+1]; the last lane clones a[..., -1]
+    (garbage)."""
     import jax.numpy as jnp
 
-    return jnp.concatenate([a[:, 1:], a[:, -1:]], axis=1)
+    return jnp.concatenate([a[..., 1:], a[..., -1:]], axis=-1)
 
 
 def shift_right(a):
-    """out[:, c] = a[:, c-1]; first lane clones a[:, 0] (garbage)."""
+    """out[..., c] = a[..., c-1]; the first lane clones a[..., 0]
+    (garbage)."""
     import jax.numpy as jnp
 
-    return jnp.concatenate([a[:, :1], a[:, :-1]], axis=1)
+    return jnp.concatenate([a[..., :1], a[..., :-1]], axis=-1)
 
 
 def slab1(A, dim, start):
@@ -109,18 +113,20 @@ def add_recv_operands(operands, in_specs, modes, recvs, field, kinds,
         in_specs.append(pl.BlockSpec(blk, imap))
 
 
-def out_shape_with_vma(a, operands):
-    """ShapeDtypeStruct for ``a`` carrying the joint mesh-axis variance of
-    every operand (shard_map's vma tracking), when the jax version has it."""
+def out_shape_with_vma(a, operands, shape=None):
+    """ShapeDtypeStruct for ``a`` (or an array of ``a``'s dtype and
+    ``shape``) carrying the joint mesh-axis variance of every operand
+    (shard_map's vma tracking), when the jax version has it."""
     import jax
 
+    shape = a.shape if shape is None else shape
     try:
         vma = jax.typeof(a).vma
         for op in operands:
             vma = vma | jax.typeof(op).vma
-        return jax.ShapeDtypeStruct(a.shape, a.dtype, vma=vma)
+        return jax.ShapeDtypeStruct(shape, a.dtype, vma=vma)
     except (AttributeError, TypeError):
-        return jax.ShapeDtypeStruct(a.shape, a.dtype)
+        return jax.ShapeDtypeStruct(shape, a.dtype)
 
 
 def vx_extra_plane_slabs(Vx, Vxn, recvs_vx, modes_vx, nx, ol_z=None):

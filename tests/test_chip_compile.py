@@ -107,9 +107,9 @@ def _assert_stokes_z_folded(txt, n):
     """The PT Stokes analog of `_assert_z_folded`: on a 2x2x1 mesh the
     fused pass folds the self-neighbor z halo of P, Vx, Vy and Vz into the
     kernel and the x/y send slabs, so no array of the program is a
-    lane-sparse z window over the x-y extent (the mini-state slab computes
-    and the concatenated z recvs, minor dim 1-4), and the PT kernel takes
-    no (.., .., 2) z operand."""
+    lane-sparse z window over the x-y extent (z send slabs and the
+    concatenated z recvs, minor dim 1-4), and the PT kernel takes no
+    (.., .., 2) z operand."""
     import re
 
     xy = rf"{n}|{n + 1}"
@@ -194,12 +194,100 @@ def test_stokes3d_runner_compiles(grid_on, n, dims, periodic, nt_chunk):
                for line in txt.splitlines())
     if gg.nprocs > 1:
         # the 4 exchanged fields ride one permute pair (left and right)
-        # per crossing axis per step; 3 custom calls a step (the PT pass
-        # and the two extra-plane writes)
-        steps = txt.count("tpu_custom_call") // 3
+        # per crossing axis per step; 5 custom calls a step: the PT pass,
+        # the two extra-plane writes and one send-slab pass per crossing
+        # axis, each under its scope
+        crossing = sum(int(v) > 1 for v in dims)
+        steps, rest = divmod(txt.count("tpu_custom_call"), 3 + crossing)
+        assert steps and not rest
         pairs = re.findall(r"collective-permute-start\(.*"
                            r"source_target_pairs=(\{\{[0-9,{}]*\}\})", txt)
-        crossing = sum(int(v) > 1 for v in dims)
-        assert steps and sorted(Counter(pairs).values()) == [2 * steps] * crossing
+        assert sorted(Counter(pairs).values()) == [2 * steps] * crossing
+        calls = [line for line in txt.splitlines()
+                 if "tpu_custom_call" in line]
+        scopes = Counter(re.search(r"igg\.stokes\.(\w+)/pallas_call",
+                                   line).group(1) for line in calls)
+        assert scopes == {"pt": steps, "vx_planes": 2 * steps,
+                          "slabs": crossing * steps}
     if dims == (2, 2, 1):
         _assert_stokes_z_folded(txt, n)
+
+
+# Lowers the 2x2x1 PT Stokes chunk program of the benchmark cell (256^3
+# per chip, chunks of 100) for a described v5e:2x2 and prints what the
+# persistent compile cache keys and gates on; exits 3 where no topology
+# can be described.
+_LOWER_STOKES_2X2 = r"""
+import hashlib, json, os, re, sys
+import numpy as np
+import jax
+jax.config.update("jax_enable_compilation_cache", False)
+try:
+    from jax.experimental import topologies
+    desc = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception:
+    sys.exit(3)
+from jax.sharding import NamedSharding
+import implicitglobalgrid_tpu as igg
+from implicitglobalgrid_tpu.models import StokesParams, make_stokes_run
+from implicitglobalgrid_tpu.ops.fields import field_partition_spec
+from implicitglobalgrid_tpu.utils import timing
+timing._device_barrier = lambda: None
+n = 256
+igg.init_global_grid(n, n, n, dimx=2, dimy=2, dimz=1, periodx=1,
+                     periody=1, periodz=1, devices=desc.devices[:4],
+                     select_device=False, quiet=True)
+gg = igg.global_grid()
+d = 10.0 / (int(gg.nxyz_g[0]) - 1)
+p = StokesParams(mu=1.0, dt_v=d * d / 6.1 / 2.0, dt_p=6.1 / 511,
+                 damp=1.0 - 6.0 / 511, dx=d, dy=d, dz=d)
+sh = NamedSharding(gg.mesh, field_partition_spec(3))
+c, fx, fy, fz = (n, n, n), (n + 1, n, n), (n, n + 1, n), (n, n, n + 1)
+args = [jax.ShapeDtypeStruct((s[0] * 2, s[1] * 2, s[2]), np.float32,
+                             sharding=sh)
+        for s in (c, fx, fy, fz, fx, fy, fz, c)]
+low = make_stokes_run(p, nt_chunk=100).lower(*args)
+text = low.as_text()
+print(json.dumps({
+    "sha256": hashlib.sha256(text.encode()).hexdigest(),
+    "host_callbacks": len(low._lowering.compile_args["host_callbacks"]),
+    "callback_ops": len(re.findall(
+        r"callback|stablehlo\.(?:send|recv)\b", text)),
+    "custom_calls": text.count("tpu_custom_call"),
+}))
+"""
+
+
+def test_stokes_2x2_chunk_program_is_cacheable():
+    """JAX's persistent cache serves a program to a later process only if
+    that process lowers it to the same text, and writes none that has host
+    callbacks: the 2x2x1 Stokes chunk program lowers alike under two hash
+    seeds (no kernel parameter built from set or dict order or an object's
+    identity) and holds no host callback."""
+    import json
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parent.parent
+    got = []
+    for seed in ("1", "2"):
+        env = dict(os.environ, PYTHONHASHSEED=seed, JAX_PLATFORMS="cpu",
+                   TPU_LOG_DIR="disabled",
+                   PYTHONPATH=str(root))
+        env.pop("XLA_FLAGS", None)
+        r = subprocess.run([sys.executable, "-c", _LOWER_STOKES_2X2],
+                           cwd=root, env=env, capture_output=True,
+                           text=True, timeout=600)
+        if r.returncode == 3:
+            pytest.skip("no v5e:2x2 topology can be described in a child "
+                        "process here")
+        assert r.returncode == 0, r.stderr[-4000:]
+        got.append(json.loads(r.stdout.strip().splitlines()[-1]))
+    assert got[0] == got[1]
+    assert got[0]["host_callbacks"] == 0 and got[0]["callback_ops"] == 0
+    # one step: the PT pass, the two extra-plane writes, the x and y
+    # send-slab passes
+    assert got[0]["custom_calls"] == 5

@@ -436,8 +436,13 @@ def test_fused_stokes_self_z_permutes():
     axis folded into the kernel and the x/y send slabs
     (`stokes_exchange_folds_z`), so the 4 exchanged fields ride one packed
     permute pair per crossing axis — 4 permutes on gx/gy, byte-exact to the
-    plan — and no op makes or takes a z slab over the x-y extent (the
-    mini-state windows and the z recvs of P, Vx, Vy and Vz)."""
+    plan — and no op makes or takes a z slab over the x-y extent (z send
+    slabs and the z recvs of P, Vx, Vy and Vz). The Pallas kernels' own
+    bodies, which the interpreter inlines as a grid loop of HLO ops, slice
+    lanes of their blocks that are never arrays on the TPU (there
+    `test_chip_compile` checks the compiled program)."""
+    import re
+
     from implicitglobalgrid_tpu.analysis import (
         axis_routes, measure_axes, model_contract,
     )
@@ -453,10 +458,12 @@ def test_fused_stokes_self_z_permutes():
     axes = measure_axes(ir, axis_routes())
     assert {a: r["permutes"] for a, r in axes.items()} == {"gx": 2, "gy": 2}
     xy = {(8, 8), (9, 8), (8, 9)}
+    in_kernel = re.compile(r'op_name="[^"]*igg\.stokes\.\w+/while/')
     z_slabs = [o.line for o in ir.find(dtype="f32")
-               if any(s.dims[:2] in xy and s.dims[2:] < (5,)
-                      for s in o.shapes + o.operand_shapes
-                      if len(s.dims) == 3)]
+               if not in_kernel.search(o.line)
+               and any(s.dims[:2] in xy and s.dims[2:] < (5,)
+                       for s in o.shapes + o.operand_shapes
+                       if len(s.dims) == 3)]
     assert not z_slabs, z_slabs[:3]
 
 
